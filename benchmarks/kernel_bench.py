@@ -79,7 +79,7 @@ def page_compact_cost(NP=4096, ptok=64, n_kv=8, dh=128,
     rng = np.random.default_rng(1)
     pool = jnp.asarray(rng.normal(size=(NP, ptok, n_kv, dh)), jnp.bfloat16)
     rows = []
-    f = jax.jit(lambda p, s, d: page_compact(p, s, d))
+    f = jax.jit(lambda p, s, d: page_compact(p, s, d, interpret=True))
     for n in batch_sizes:
         perm = rng.permutation(NP)
         src = jnp.asarray(perm[:n].astype(np.int32))

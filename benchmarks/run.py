@@ -120,6 +120,9 @@ def main(argv=None):
     n = 2000 if args.fast else 4000
 
     from benchmarks import kernel_bench, paperfigs, serving_bench
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = {
         "fig1": lambda: paperfigs.fig1_translation_overhead(n_access=n),

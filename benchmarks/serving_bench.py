@@ -1540,7 +1540,8 @@ def fused_kernel_compare(B: int = 4, nblk: int = 8, reps: int = 3) -> List[Dict]
     scale = 1.0 / float(np.sqrt(dh))
 
     base = paged_attention_kernel(q, pool_k, pool_v, tables, ntok,
-                                  granularity="page", scale=scale)
+                                  granularity="page", scale=scale,
+                                  interpret=True)
 
     # All resident: every slot -1, stage pools untouched.
     no_slots = jnp.full((B, nblk), -1, jnp.int32)
@@ -1548,7 +1549,7 @@ def fused_kernel_compare(B: int = 4, nblk: int = 8, reps: int = 3) -> List[Dict]
     stage_v = pool_v[:4]
     allready = fused_paged_attention_kernel(
         q, pool_k, pool_v, stage_k, stage_v, tables, no_slots, ntok,
-        scale=scale)
+        scale=scale, interpret=True)
     bitwise = all(
         np.array_equal(np.asarray(a), np.asarray(b))
         for a, b in zip(allready, base))
@@ -1570,7 +1571,8 @@ def fused_kernel_compare(B: int = 4, nblk: int = 8, reps: int = 3) -> List[Dict]
     slots = jnp.asarray(slots_np)
     fused = fused_paged_attention_kernel(
         q, jnp.asarray(dirty_k), jnp.asarray(dirty_v),
-        jnp.asarray(sk), jnp.asarray(sv), tables, slots, ntok, scale=scale)
+        jnp.asarray(sk), jnp.asarray(sv), tables, slots, ntok, scale=scale,
+        interpret=True)
     partial_ok = all(
         np.allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
         for a, b in zip(fused, base))
@@ -1590,11 +1592,13 @@ def fused_kernel_compare(B: int = 4, nblk: int = 8, reps: int = 3) -> List[Dict]
         gv[tbl_np[late]] = sv
         return paged_attention_kernel(q, jnp.asarray(gk), jnp.asarray(gv),
                                       tables, ntok,
-                                      granularity="page", scale=scale)
+                                      granularity="page", scale=scale,
+                                      interpret=True)
 
     t_fused = _time(lambda: fused_paged_attention_kernel(
         q, jnp.asarray(dirty_k), jnp.asarray(dirty_v),
-        jnp.asarray(sk), jnp.asarray(sv), tables, slots, ntok, scale=scale))
+        jnp.asarray(sk), jnp.asarray(sv), tables, slots, ntok, scale=scale,
+        interpret=True))
     t_gather = _time(_gather_then_attend)
     toks = B * nblk * ptok
     rows = [

@@ -157,12 +157,25 @@ class CoCoA:
         fp = self.pool.config.frame_pages
         vpns: List[int] = []
         touched_vframes = set()
-        for _ in range(n_pages):
-            vpn = table.num_pages
-            ppn, _ = self._alloc_slot(owner, table, vpn % fp)
-            table.append(ppn)
-            vpns.append(vpn)
-            touched_vframes.add(table.vframe_of(vpn))
+        try:
+            for _ in range(n_pages):
+                vpn = table.num_pages
+                ppn, _ = self._alloc_slot(owner, table, vpn % fp)
+                table.append(ppn)
+                vpns.append(vpn)
+                touched_vframes.add(table.vframe_of(vpn))
+        except OutOfMemory:
+            # All or nothing: hand this call's pages back before failing,
+            # so a retry (or the caller's rollback) starts from the table
+            # as it was.
+            for _ in vpns:
+                ppn = table.ppn.pop()
+                self.pool.free_page(ppn)
+                f = self.pool.frame_of(ppn)
+                if self.pool.frame_owner[f] == owner:   # not released
+                    self._note_partial(owner, f)
+            del table.coalesced[table.num_vframes:]
+            raise
         self.coalescer.coalesce_all(table, touched_vframes)
         return vpns
 

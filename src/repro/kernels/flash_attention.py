@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -80,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
-                    bq: int = 128, bk: int = 512, interpret: bool = True):
+                    bq: int = 128, bk: int = 512, interpret: bool = False):
     """q [B,T,H,dh]; k/v [B,T,Hkv,dh{,_v}] -> [B,T,H,dh_v].
 
     T must be a multiple of bq and bk (pad upstream; the model path pads).
@@ -132,7 +131,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
             pltpu.VMEM((g * bq,), jnp.float32),
             pltpu.VMEM((g * bq, dh_v), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kr, vr)

@@ -1,10 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` selects the kernel path (interpret=True on CPU — executes
-the kernel body in Python for correctness; on real TPUs pass
-``interpret=False``).  The default is the pure-JAX path from
-:mod:`repro.models`, which is what the dry-run lowers (Pallas cannot
-compile for the CPU placeholder devices).
+``use_pallas`` selects the Pallas kernel, compiled for the TPU; on the
+CPU a caller passes ``interpret=True`` to run the kernel body in the
+Pallas interpreter instead.  ``use_pallas=False`` runs the pure-JAX
+oracles in :mod:`repro.kernels.ref`.
 """
 
 from __future__ import annotations
@@ -31,7 +30,7 @@ from repro.kernels.paged_attention import (
 @functools.partial(jax.jit, static_argnames=("causal", "use_pallas",
                                              "interpret", "bq", "bk"))
 def flash_attention(q, k, v, *, causal: bool = True,
-                    use_pallas: bool = False, interpret: bool = True,
+                    use_pallas: bool = False, interpret: bool = False,
                     bq: int = 128, bk: int = 512):
     if use_pallas:
         return _flash_kernel(q, k, v, causal=causal, bq=bq, bk=bk,
@@ -44,7 +43,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
 def paged_attention_dual(q, pool_k, pool_v, frame_tables, frame_ntok,
                          page_tables, page_ntok, *, frame_pages: int,
                          scale: float, use_pallas: bool = False,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """Dual-granularity paged attention over one shard's pool.
 
     Coalesced frames go down the frame fast path; splintered pages down the
@@ -81,7 +80,7 @@ def paged_attention_dual(q, pool_k, pool_v, frame_tables, frame_ntok,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def page_compact(pool, src, dst, *, use_pallas: bool = True,
-                 interpret: bool = True):
+                 interpret: bool = False):
     if use_pallas:
         return _compact_kernel(pool, src, dst, interpret=interpret)
     return ref.page_compact_ref(pool, src, dst)
@@ -89,7 +88,7 @@ def page_compact(pool, src, dst, *, use_pallas: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def page_gather(pool, idx, *, use_pallas: bool = True,
-                interpret: bool = True):
+                interpret: bool = False):
     """Host-tier eviction gather: pages[i] = pool[idx[i]] (DESIGN.md §6)."""
     if use_pallas:
         return _gather_kernel(pool, idx, interpret=interpret)
@@ -98,7 +97,7 @@ def page_gather(pool, idx, *, use_pallas: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def page_scatter(pool, idx, pages, *, use_pallas: bool = True,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Host-tier fault-in scatter: pool[idx[i]] = pages[i] (DESIGN.md §6)."""
     if use_pallas:
         return _scatter_kernel(pool, idx, pages, interpret=interpret)
@@ -108,7 +107,7 @@ def page_scatter(pool, idx, pages, *, use_pallas: bool = True,
 @functools.partial(jax.jit, static_argnames=("chunk", "use_pallas",
                                              "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, h0=None,
-             use_pallas: bool = False, interpret: bool = True):
+             use_pallas: bool = False, interpret: bool = False):
     """Mamba-2 SSD chunked scan (see kernels/ssd_scan.py)."""
     if use_pallas:
         from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel

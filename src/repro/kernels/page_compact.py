@@ -30,14 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 
 def _copy_kernel(src_ref, dst_ref, pool_in_ref, pool_out_ref):
     pool_out_ref[...] = pool_in_ref[...]
 
 
-def page_compact(pool, src, dst, *, interpret: bool = True):
+def page_compact(pool, src, dst, *, interpret: bool = False):
     """pool [NP, ptok, kv, dh]; src/dst int32 [n].  Returns updated pool.
 
     input_output_aliasing keeps this in-place on the real device: only the
@@ -72,7 +71,7 @@ def page_compact(pool, src, dst, *, interpret: bool = True):
         ),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(src, dst, pool)
@@ -89,7 +88,7 @@ def _gather_kernel(idx_ref, pool_in_ref, out_ref):
     out_ref[...] = pool_in_ref[...]
 
 
-def page_gather(pool, idx, *, interpret: bool = True):
+def page_gather(pool, idx, *, interpret: bool = False):
     """pool [NP, ptok, kv, dh]; idx int32 [n] → pages [n, ptok, kv, dh].
 
     One page-sized DMA per grid step, both sides scalar-prefetch-addressed;
@@ -115,7 +114,7 @@ def page_gather(pool, idx, *, interpret: bool = True):
             out_specs=pl.BlockSpec(blk, out_index),
         ),
         out_shape=jax.ShapeDtypeStruct((n, *pool.shape[1:]), pool.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, pool)
@@ -125,7 +124,7 @@ def _scatter_kernel(idx_ref, row_ref, pages_ref, pool_in_ref, pool_out_ref):
     pool_out_ref[...] = pages_ref[...]
 
 
-def page_scatter(pool, idx, pages, *, interpret: bool = True):
+def page_scatter(pool, idx, pages, *, interpret: bool = False):
     """pool [NP, ...]; idx int32 [n]; pages [n, ...] → pool'.
 
     pool'[idx[i]] = pages[i].  Holes (idx = -1) are rewritten to duplicates
@@ -164,7 +163,7 @@ def page_scatter(pool, idx, pages, *, interpret: bool = True):
         ),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(safe_idx, src_row, pages, pool)

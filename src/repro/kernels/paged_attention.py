@@ -21,9 +21,10 @@ hardware page-table walk: translation happens in the DMA descriptor
 stream, and its *cost* is the number of descriptors (table entries)
 consumed per KV byte.
 
-Grid: (batch, n_blocks) with the KV axis iterated sequentially
-("arbitrary") per sequence; the flash accumulator lives in VMEM scratch
-and is flushed on the last block.
+Grid: (batch, n_blocks, slices) with the KV axes iterated sequentially
+("arbitrary") per sequence; a frame too large for one step's VMEM is
+read as consecutive slices of its slab.  The flash accumulator lives in
+VMEM scratch and is flushed on the last step.
 """
 
 from __future__ import annotations
@@ -34,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -71,36 +70,54 @@ def _flash_step(q, k, v, valid, m_s, l_s, o_s, *, first: bool):
 
 def _paged_kernel(tables_ref, ntok_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, m_s, l_s, o_s, *,
-                  tokens_per_block: int, scale: float):
+                  tokens_per_step: int, scale: float):
     """Shared body for both granularities.
 
     Block shapes (leading batch block of 1 squeezed by indexing):
       q_ref [1, kv, g, dh]; k_ref [1, T, kv, dh]; v_ref [1, T, kv, dh_v]
-      (T = tokens_per_block: one page or one whole frame)
+      (T = tokens_per_step: one page, or one contiguous slice of a frame)
       outputs: o_ref [1, kv, g, dh_v]; m_ref/l_ref [1, kv, g]
     """
     blk = pl.program_id(1)
-    nblk = pl.num_programs(1)
+    sub = pl.program_id(2)
+    first = (blk == 0) & (sub == 0)
     q = q_ref[0].astype(jnp.float32) * scale
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    nt = ntok_ref[pl.program_id(0), blk]
+    nt = ntok_ref[pl.program_id(0), blk] - sub * tokens_per_step
     valid = jax.lax.broadcasted_iota(
-        jnp.int32, (tokens_per_block,), 0) < nt
+        jnp.int32, (tokens_per_step,), 0) < nt
 
-    @pl.when(blk == 0)
+    @pl.when(first)
     def _init():
         _flash_step(q, k, v, valid, m_s, l_s, o_s, first=True)
 
-    @pl.when(blk != 0)
+    @pl.when(jnp.logical_not(first))
     def _acc():
         _flash_step(q, k, v, valid, m_s, l_s, o_s, first=False)
 
-    @pl.when(blk == nblk - 1)
+    @pl.when((blk == pl.num_programs(1) - 1)
+             & (sub == pl.num_programs(2) - 1))
     def _flush():
         o_ref[0] = o_s[...]
         m_ref[0] = m_s[...]
         l_ref[0] = l_s[...]
+
+
+# Most KV tokens one grid step reads.  A v5e compiles 128-token steps for
+# every KV geometry of the config catalog (2 to 32 KV heads of 64 to 128,
+# bf16 or f32 pools) and refuses 256-token steps at most of them: scoped
+# VMEM holds the double-buffered k/v blocks and their f32 working copies.
+STEP_TOKENS = 128
+
+
+def _step_pages(pages_per_block: int, ptok: int) -> int:
+    """Pages one grid step reads: the largest divisor of the block that
+    holds at most :data:`STEP_TOKENS` tokens (one page if a page alone is
+    larger)."""
+    return max(sp for sp in range(1, pages_per_block + 1)
+               if pages_per_block % sp == 0
+               and (sp == 1 or sp * ptok <= STEP_TOKENS))
 
 
 def paged_attention_kernel(
@@ -108,7 +125,7 @@ def paged_attention_kernel(
     granularity: str,            # 'page' | 'frame'
     frame_pages: int = 16,
     scale: float = 1.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Launch one granularity's kernel.
 
@@ -116,48 +133,50 @@ def paged_attention_kernel(
     tables [B, n_blocks] (page ids or frame ids; -1 holes);
     ntok [B, n_blocks] valid tokens per block.
     Returns unnormalized (o [B,H,dh_v] f32, m [B,H] f32, l [B,H] f32).
+
+    A frame is read as ``frame_pages / step_pages`` consecutive slices of
+    one contiguous slab (an inner grid axis), so one table entry still
+    addresses the whole frame while each step's block fits scoped VMEM.
     """
     B, H, dh = q.shape
     NP, ptok, n_kv, _ = pool_k.shape
     dh_v = pool_v.shape[-1]
     g = H // n_kv
     nblocks = tables.shape[1]
-    if granularity == "frame":
-        pages_per_block = frame_pages
-    else:
-        pages_per_block = 1
-    tpb = pages_per_block * ptok
+    pages_per_block = frame_pages if granularity == "frame" else 1
+    sp = _step_pages(pages_per_block, ptok)
+    nsub = pages_per_block // sp
+    tps = sp * ptok
 
-    # View pools as [NP // pages_per_block, tpb, kv, dh]: one block = one
-    # page or one aligned frame (contiguous slab — the Mosaic fast path).
-    pk = pool_k.reshape(NP // pages_per_block, tpb, n_kv, dh)
-    pv = pool_v.reshape(NP // pages_per_block, tpb, n_kv, dh_v)
+    # View pools as [NP // sp, tps, kv, dh]: block ``i * nsub + j`` is the
+    # j-th slice of aligned block i (one page, or one frame's slab).
+    pk = pool_k.reshape(NP // sp, tps, n_kv, dh)
+    pv = pool_v.reshape(NP // sp, tps, n_kv, dh_v)
     qg = q.reshape(B, n_kv, g, dh)
 
-    def q_index(b, blk, tables, ntok):
+    def q_index(b, blk, sub, tables, ntok):
         return (b, 0, 0, 0)
 
-    def kv_index(b, blk, tables, ntok):
-        return (jnp.maximum(tables[b, blk], 0), 0, 0, 0)
+    def kv_index(b, blk, sub, tables, ntok):
+        return (jnp.maximum(tables[b, blk], 0) * nsub + sub, 0, 0, 0)
 
-    def out_index(b, blk, tables, ntok):
+    def out_index(b, blk, sub, tables, ntok):
         return (b, 0, 0)
 
-    def out_index4(b, blk, tables, ntok):
+    def out_index4(b, blk, sub, tables, ntok):
         return (b, 0, 0, 0)
 
-    grid = (B, nblocks)
     kernel = functools.partial(
-        _paged_kernel, tokens_per_block=tpb, scale=scale)
+        _paged_kernel, tokens_per_step=tps, scale=scale)
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B, nblocks, nsub),
             in_specs=[
                 pl.BlockSpec((1, n_kv, g, dh), q_index),
-                pl.BlockSpec((1, tpb, n_kv, dh), kv_index),
-                pl.BlockSpec((1, tpb, n_kv, dh_v), kv_index),
+                pl.BlockSpec((1, tps, n_kv, dh), kv_index),
+                pl.BlockSpec((1, tps, n_kv, dh_v), kv_index),
             ],
             out_specs=[
                 pl.BlockSpec((1, n_kv, g, dh_v), out_index4),
@@ -175,8 +194,8 @@ def paged_attention_kernel(
             jax.ShapeDtypeStruct((B, n_kv, g), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, g), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tables, ntok, qg, pk, pv)
     return (o.reshape(B, H, dh_v), m.reshape(B, H), l.reshape(B, H))
@@ -293,7 +312,7 @@ def readiness_meta(slots):
 def fused_paged_attention_kernel(
     q, pool_k, pool_v, stage_k, stage_v, tables, slots, ntok, *,
     scale: float = 1.0,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Decode attention over partially-resident KV (DESIGN.md §13).
 
@@ -364,7 +383,7 @@ def fused_paged_attention_kernel(
             jax.ShapeDtypeStruct((B, n_kv, g), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, g), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, ntok, slots, meta, qg, pool_k, pool_v, stage_k, stage_v)

@@ -86,8 +86,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
              remat: str = "block", tp: int = 0,
              microbatch: int = 0, grad_compress: bool = False):
     import jax
-    from repro import compat
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.launch.specs import build_cell
     from repro.configs import get_config, SHAPES
     from repro.configs.base import TrainHParams
@@ -104,13 +103,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         shape_axes = ((2, per_pod, tp) if multi_pod else (per_pod, tp))
         names = (("pod", "data", "model") if multi_pod
                  else ("data", "model"))
-        from repro.compat import make_mesh
         mesh = make_mesh(shape_axes, names)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         cell = build_cell(arch, shape_name, mesh, hp=hp)
         jitted = jax.jit(
             cell["fn"],

@@ -14,14 +14,18 @@ the tensor/expert-parallel axis kept inside an ICI-adjacent 16-chip ring.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh as _mesh
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis of type Auto (GSPMD sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_elastic_mesh(n_devices: int, *, model: int = 16):
@@ -33,7 +37,7 @@ def make_elastic_mesh(n_devices: int, *, model: int = 16):
     m = model
     while m > 1 and n_devices % m:
         m //= 2
-    return _mesh((n_devices // m, m), ("data", "model"))
+    return make_mesh((n_devices // m, m), ("data", "model"))
 
 
 def make_host_mesh():

@@ -58,9 +58,11 @@ def main():
                          "translation-cycle summary line")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.cluster import ServingCluster
     from repro.serving.engine import Request, ServingEngine
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)
     geo = PoolGeometry(

@@ -68,9 +68,8 @@ def shd(x: jax.Array, *spec) -> jax.Array:
     head-sharding hint degrades to replicated under fsdp, where 'model'
     belongs to the batch).
     """
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
     used: set = set()
@@ -102,8 +101,7 @@ def psum_point(x: jax.Array) -> jax.Array:
     barrier is linear, so its transpose pins the backward all-reduce at
     the cotangent's dtype at the same point.
     """
-    from repro.compat import optimization_barrier
-    return optimization_barrier(x)
+    return jax.lax.optimization_barrier(x)
 
 
 def dense_init(key, shape, in_axis: int = 0, dtype=jnp.float32):
@@ -122,7 +120,9 @@ def split_keys(key, n: int):
 
 
 def cast(tree, dtype):
+    """Floating leaves to ``dtype``; leaves already in it are kept as is."""
     return jax.tree.map(
-        lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        lambda x: x.astype(dtype)
+        if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != dtype else x,
         tree,
     )

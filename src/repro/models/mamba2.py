@@ -89,20 +89,12 @@ def _head_expand(cfg: ModelConfig, Bc):
     return jnp.repeat(Bc, hpg, axis=2)
 
 
-USE_PALLAS_SSD = False   # flip on real TPUs (interpret=False); the jnp
-                         # path below is the oracle and the dry-run path.
-
-
 def ssd_chunked(xh, dt, A, Bh, Ch, chunk: int, h0=None):
     """Chunked SSD scan (pure JNP oracle for the Pallas ``ssd_scan`` kernel).
 
     xh [B,T,nh,hd]; dt [B,T,nh] (post-softplus); A [nh] (negative);
     Bh/Ch [B,T,nh,N].  Returns (y [B,T,nh,hd], h_final [B,nh,hd,N]).
     """
-    if USE_PALLAS_SSD:
-        from repro.kernels.ssd_scan import ssd_scan as _kernel
-        return _kernel(xh, dt.astype(jnp.float32), A, Bh, Ch, chunk=chunk,
-                       h0=h0)
     Bsz, T, nh, hd = xh.shape
     N = Bh.shape[-1]
     assert T % chunk == 0, (T, chunk)

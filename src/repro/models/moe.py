@@ -30,7 +30,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import get_abstract_mesh, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -85,8 +84,8 @@ def capacity(S: int, E: int, top_k: int, cf: float) -> int:
 
 
 def _ambient_mesh():
-    mesh = get_abstract_mesh()
-    return None if (mesh is None or mesh.empty) else mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _moe_routed(cfg: ModelConfig, p, x, e0, E_local, axes):
@@ -206,7 +205,7 @@ def moe_block(cfg: ModelConfig, p, x) -> Tuple[jax.Array, jax.Array]:
     if mo.n_shared:
         in_specs += [P(None, fs_ax), P(None, fs_ax), P(fs_ax, None)]
         args += [p["ws_gate"], p["ws_up"], p["ws_down"]]
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=(P(bs, None, None), P()), check_vma=False)
     y, aux = fn(*args)
     return shd(y, DP, None, None), aux
@@ -300,7 +299,7 @@ def _moe_block_serving(cfg: ModelConfig, p, x, mesh):
     if mo.n_shared:
         in_specs += [P(None, "model"), P(None, "model"), P("model", None)]
         args += [p["ws_gate"], p["ws_up"], p["ws_down"]]
-    fn = shard_map(local_decode if decode else local_prefill, mesh=mesh,
+    fn = jax.shard_map(local_decode if decode else local_prefill, mesh=mesh,
                    in_specs=tuple(in_specs),
                    out_specs=(P(bs, None, None), P()), check_vma=False)
     y, aux = fn(*args)

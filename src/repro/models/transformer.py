@@ -22,8 +22,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro import compat
-from repro.compat import get_abstract_mesh, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -106,8 +104,8 @@ jax.tree_util.register_dataclass(
 
 
 def _ambient_mesh():
-    mesh = get_abstract_mesh()
-    return None if (mesh is None or mesh.empty) else mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 # ------------------------------------------------------------------ attention
@@ -286,7 +284,7 @@ def _attn_block_train_tp(cfg: ModelConfig, p, x, positions, mesh, geo, *,
     if has_qkn:
         in_specs += [P(None), P(None)]
         args += [p["q_norm"], p["k_norm"]]
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=(P(bs, None, None), kv_out, kv_out),
                    check_vma=False)
     return fn(*args)
@@ -331,7 +329,7 @@ def paged_attn_op(q, k_new, v_new, k_pool, v_pool, ctx: PageCtx, *, scale):
     axes = ctx.page_axes(mesh)
     bs = ctx.batch_spec(mesh)
     pool_spec = P(ctx.pool_axes(mesh) or None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(local, axes=axes),
         mesh=mesh,
         in_specs=(
@@ -365,7 +363,7 @@ def prefill_write_op(k_seq, v_seq, k_pool, v_pool, ctx: PageCtx,
         tables = tables.reshape(tables.shape[0], -1)
         shard, n_shards = 0, 1
         for a in axes:
-            n = compat.axis_size(a)
+            n = jax.lax.axis_size(a)
             shard = shard * n + jax.lax.axis_index(a)
             n_shards *= n
         return paged.write_prefill_kv(
@@ -378,7 +376,7 @@ def prefill_write_op(k_seq, v_seq, k_pool, v_pool, ctx: PageCtx,
     axes = ctx.page_axes(mesh)
     bs = ctx.batch_spec(mesh)
     pool_spec = P(ctx.pool_axes(mesh) or None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(local, axes=axes), mesh=mesh,
         in_specs=(P(bs), P(bs), pool_spec, pool_spec, P(bs, axes)),
         out_specs=(pool_spec, pool_spec),
@@ -433,7 +431,7 @@ def ffn_block(cfg: ModelConfig, p, x):
         h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
         return jax.lax.psum(jnp.einsum("btf,fd->btd", h, wd), "model")
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(bs, None, None), P(None, "model"),
                              P(None, "model"), P("model", None)),
                    out_specs=P(bs, None, None), check_vma=False)

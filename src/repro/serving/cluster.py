@@ -1209,11 +1209,13 @@ class ClusterStats:
 class ServingCluster:
     """N :class:`ServingEngine` replicas + shared host tier + router.
 
-    All replicas share one ``params`` pytree (replica equivalence is what
-    makes cross-engine prefix reuse and migration bitwise-safe), their
-    own pools/DMA lanes/clocks, and — unless ``share_host=False`` (the
-    per-engine baseline the benches compare against) — one
-    :class:`SharedHostTier`.
+    All replicas serve one set of weights (replica equivalence is what
+    makes cross-engine prefix reuse and migration bitwise-safe); engine
+    ``i`` holds them, its pools and its step inputs on device ``i`` (mod
+    the device count), so on a multi-chip host each replica has a chip
+    of its own.  Each has its own pools/DMA lanes/clocks, and — unless
+    ``share_host=False`` (the per-engine baseline the benches compare
+    against) — all share one :class:`SharedHostTier`.
     """
 
     def __init__(self, cfg: ModelConfig, *, geometry: PoolGeometry,
@@ -1276,7 +1278,7 @@ class ServingCluster:
                 prefix_capacity_pages=prefix_capacity_pages,
                 injector=fault_injector,
                 **engine_kw)
-            params = eng.params          # replicas share one weight tree
+            params = eng.params          # one weight tree, copied per device
             self.engines.append(eng)
         self.router = RequestRouter(self.engines, tier=self.tier,
                                     policy=router_policy, migrate=migrate,

@@ -84,6 +84,7 @@ pool writes) is a single jitted call (the hardware half).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
@@ -96,11 +97,25 @@ from repro.configs.base import ModelConfig, PoolGeometry
 from repro.core.cocoa import OutOfMemory
 from repro.core.demand_paging import LinkModel
 from repro.kernels import ops as kops
+from repro.models.common import cast
 from repro.models.lm import LM
 from repro.serving.dma import (AsyncDMAEngine, DMAJob, Key, Prefetcher,
                                StagingBuffer)
 from repro.serving.host_tier import HostPageStore, PrefixIndex
 from repro.serving.kv_cache import ShardedKVCache
+
+
+@functools.lru_cache(maxsize=None)
+def _model_fns(cfg: ModelConfig):
+    """Jitted (init, decode_step, prefill) of ``cfg``'s model, shared by
+    every engine of the process so replicas compile each shape once.
+    ``init`` yields the weights straight in ``cfg.dtype``: no float32
+    copy of the tree is ever resident on the device."""
+    lm = LM(cfg)
+    dtype = jnp.dtype(cfg.dtype)
+    return (jax.jit(lambda key: cast(lm.init(key), dtype)),
+            jax.jit(lm.decode_step),
+            jax.jit(lm.prefill, static_argnames=("prefix_len",)))
 
 
 @dataclasses.dataclass
@@ -368,6 +383,10 @@ class ServingEngine:
         # Replica identity within a cluster (DESIGN.md §10): the host-tier
         # frame-lease protection domain and the reporting label.
         self.engine_id = engine_id
+        # Replica placement: engine i keeps its weights, pools and step
+        # inputs on device i (mod the device count); a lone engine uses
+        # device 0.
+        self.device = jax.devices()[engine_id % jax.device_count()]
         # Failure model (DESIGN.md §12): False after an injected crash —
         # the router stops dispatching here and recovers the workload.
         self.alive = True
@@ -444,12 +463,16 @@ class ServingEngine:
         elif prefix_cache and self.prefix_supported:
             self.prefix = PrefixIndex(self.host, geometry.page_tokens,
                                       capacity_pages=prefix_capacity_pages)
-        self.params = params if params is not None else self.lm.init(
-            jax.random.PRNGKey(seed))
+        # Weights live in the model's dtype, so the per-call cast inside
+        # the model is a no-op.
+        init, self._decode_jit, self._prefill_jit = _model_fns(cfg)
+        if params is None:
+            params = init(self._dev(jax.random.PRNGKey(seed)))
+        self.params = self._dev(cast(params, jnp.dtype(cfg.dtype)))
         shapes = self.lm.pool_shapes(per_shard * n_shards,
                                      geometry.page_tokens)
-        self.pools = (tuple(jnp.zeros(s.shape, s.dtype) for s in shapes)
-                      if shapes else None)
+        self.pools = (tuple(self._dev(np.zeros(s.shape, s.dtype))
+                            for s in shapes) if shapes else None)
         self.states: Dict[int, dict] = {}
         self.queue: Deque[Request] = deque()
         self.preempted: Deque[Request] = deque()
@@ -497,9 +520,11 @@ class ServingEngine:
         self._fused_jobs: List[DMAJob] = []
         self._fused_staged: List[tuple] = []
         self._fused_t0 = 0.0
-        self._decode_jit = jax.jit(
-            lambda p, t, pos, pools, ctx, st: self.lm.decode_step(
-                p, t, pos, pools, ctx, st))
+
+    def _dev(self, tree):
+        """Commit host data (or another device's arrays) to this
+        engine's device."""
+        return jax.device_put(tree, self.device)
 
     # ------------------------------------------------------------- admission
 
@@ -626,8 +651,8 @@ class ServingEngine:
         if not entries or self.pools is None:
             return []
         pps = self.cache.pages_per_shard
-        gidx = jnp.asarray([s * pps + ppn for s, _v, ppn in entries],
-                           jnp.int32)
+        gidx = self._dev(np.asarray([s * pps + ppn for s, _v, ppn in entries],
+                                    np.int32))
         k, v = self.pools
         kp = jax.vmap(lambda pool: kops.page_gather(
             pool, gidx, use_pallas=self.use_pallas))(k)
@@ -755,7 +780,7 @@ class ServingEngine:
         self._foreign.discard(r.rid)
         self.preempted.append(r)
         if bundle["state"] is not None:
-            self.states[r.rid] = bundle["state"]
+            self.states[r.rid] = self._dev(bundle["state"])
         self._saved_tokens[r.rid] = bundle["saved_tokens"]
         self.stats.migrations_in += 1
 
@@ -993,9 +1018,9 @@ class ServingEngine:
         """Land faulted payloads in the device pools (one batched launch)."""
         if self.pools is None or not gidx:
             return
-        idx = jnp.asarray(gidx, jnp.int32)
-        kp = jnp.asarray(np.stack([p[0] for p in payloads], axis=1))
-        vp = jnp.asarray(np.stack([p[1] for p in payloads], axis=1))
+        idx = self._dev(np.asarray(gidx, np.int32))
+        kp = self._dev(np.stack([p[0] for p in payloads], axis=1))
+        vp = self._dev(np.stack([p[1] for p in payloads], axis=1))
         k, v = self.pools
         k = jax.vmap(lambda pool, pages: kops.page_scatter(
             pool, idx, pages, use_pallas=self.use_pallas))(k, kp)
@@ -1244,7 +1269,7 @@ class ServingEngine:
         pps = self.cache.pages_per_shard
         gid = {s * pps + ppn: i
                for i, ((s, ppn), _pl, _t) in enumerate(self._fused_staged)}
-        tables = np.asarray(ctx.tables)
+        tables = ctx.tables
         slots = np.full(tables.shape, -1, np.int32)
         for g, i in gid.items():
             slots[tables == g] = i
@@ -1258,9 +1283,7 @@ class ServingEngine:
             vp = np.concatenate(
                 [vp, np.zeros((vp.shape[0], ns - vp.shape[1],
                                *vp.shape[2:]), vp.dtype)], axis=1)
-        return dataclasses.replace(
-            ctx, slots=jnp.asarray(slots),
-            stage_k=jnp.asarray(kp), stage_v=jnp.asarray(vp))
+        return dataclasses.replace(ctx, slots=slots, stage_k=kp, stage_v=vp)
 
     def _settle_fused(self) -> None:
         """Post-decode sync point (DESIGN.md §13): the kernel drained
@@ -1491,12 +1514,11 @@ class ServingEngine:
         tokens = np.full((1, Tpad - P), 0, np.int32)
         tokens[0, :T - P] = req.prompt[P:]
         ctx = self._ctx_global(self.cache.pack_ctx([req.rid], self.mpps))
-        logits, pools_new, state = self.lm.prefill(
-            self.params, {"tokens": jnp.asarray(tokens)},
-            self._pools_for([req.rid]), ctx,
-            last_pos=jnp.asarray([T - 1 - P], jnp.int32),
-            prefix_kv=(jnp.asarray(pk), jnp.asarray(pv)), prefix_len=P)
-        self._merge_pools([req.rid], pools_new)
+        logits, self.pools, state = self._prefill_jit(
+            self.params, self._dev({"tokens": tokens}), self.pools,
+            self._dev(ctx),
+            last_pos=self._dev(np.asarray([T - 1 - P], np.int32)),
+            prefix_kv=self._dev((pk, pv)), prefix_len=P)
         self.states[req.rid] = state
         req.out.append(int(jnp.argmax(logits[0])))
         self.stats.prefill_tokens += T - P      # compute actually done
@@ -1625,17 +1647,17 @@ class ServingEngine:
         ctx = self._ctx_global(self.cache.pack_ctx([req.rid], self.mpps))
         tokens = np.full((1, Tpad), 0, np.int32)
         tokens[0, :T] = req.prompt
-        batch = {"tokens": jnp.asarray(tokens)}
+        batch = {"tokens": tokens}
         if self.cfg.family == "vlm":
-            batch["patch_embeds"] = jnp.zeros(
-                (1, self.cfg.frontend_tokens, self.cfg.d_model))
+            batch["patch_embeds"] = np.zeros(
+                (1, self.cfg.frontend_tokens, self.cfg.d_model), np.float32)
         if self.cfg.family == "encdec":
-            batch["src_embeds"] = jnp.zeros(
-                (1, self.cfg.encdec.source_len, self.cfg.d_model))
-        logits, pools_new, state = self.lm.prefill(
-            self.params, batch, self._pools_for([req.rid]), ctx,
-            last_pos=jnp.asarray([T - 1], jnp.int32))
-        self._merge_pools([req.rid], pools_new)
+            batch["src_embeds"] = np.zeros(
+                (1, self.cfg.encdec.source_len, self.cfg.d_model),
+                np.float32)
+        logits, self.pools, state = self._prefill_jit(
+            self.params, self._dev(batch), self.pools, self._dev(ctx),
+            last_pos=self._dev(np.asarray([T - 1], np.int32)))
         self.states[req.rid] = state
         nxt = int(jnp.argmax(logits[0]))
         req.out.append(nxt)
@@ -1645,23 +1667,16 @@ class ServingEngine:
 
     # ------------------------------------------------------------- pools
 
-    # For simplicity pools are global arrays addressed by global page id =
+    # Pools are global arrays addressed by global page id =
     # shard * pages_per_shard + local id; pack_ctx returns local ids, so we
-    # offset per shard here.
-    def _pools_for(self, seqs):
-        return self.pools
-
-    def _merge_pools(self, seqs, pools_new):
-        self.pools = pools_new
-
+    # offset per shard here (host-side, before the step's device put).
     def _ctx_global(self, ctx):
         """Convert per-shard local page ids to global pool ids."""
-        S = self.cache.S
-        pps = self.cache.pages_per_shard
-        off = (jnp.arange(S) * pps)[None, :, None]
-        tables = jnp.where(ctx.tables >= 0, ctx.tables + off, -1)
-        woff = (jnp.arange(S) * pps)[None, :]
-        wpage = jnp.where(ctx.wpage >= 0, ctx.wpage + woff, -1)
+        off = np.arange(self.cache.S) * self.cache.pages_per_shard
+        tables = np.where(ctx.tables >= 0, ctx.tables + off[None, :, None],
+                          -1).astype(np.int32)
+        wpage = np.where(ctx.wpage >= 0, ctx.wpage + off[None, :],
+                         -1).astype(np.int32)
         return dataclasses.replace(ctx, tables=tables, wpage=wpage)
 
     # ------------------------------------------------------------- stepping
@@ -1760,7 +1775,14 @@ class ServingEngine:
                     self._settle_fused()
                 self.stats.wall_s += time.perf_counter() - t0
                 return bool(self.active or self.queue or self.preempted)
-        ctx = self._ctx_global(self.cache.pack_ctx(seqs, self.mpps))
+        # Decode always runs max_batch rows; idle rows read no page and
+        # write nowhere.  One compiled step, and each row's numerics stay
+        # independent of which requests share the step: on the TPU a
+        # matmul's rounding can depend on its row count, which would let
+        # the memory manager's scheduling change the tokens.
+        rows = self.max_batch
+        ctx = self._ctx_global(self.cache.pack_ctx(seqs, self.mpps,
+                                                   rows=rows))
         if self.fault_mode == "fused":
             # Start-decode-on-resident: hand the kernel this step's
             # staged arrivals + readiness mask instead of stalling for
@@ -1770,13 +1792,16 @@ class ServingEngine:
             # Stage 2: predicted next-step touches ride the DMA channels
             # while the decode below computes — their µs become hidden.
             self._issue_prefetch()
-        toks = jnp.asarray([r.out[-1] for r in runnable], jnp.int32)
-        pos = jnp.asarray([self.cache.seq_tokens[r.rid] - 1
-                           for r in runnable], jnp.int32)
-        state = self._stack_states(seqs)
+        toks = np.zeros(rows, np.int32)
+        pos = np.zeros(rows, np.int32)
+        for i, r in enumerate(runnable):
+            toks[i] = r.out[-1]
+            pos[i] = self.cache.seq_tokens[r.rid] - 1
+        state = self._stack_states(seqs, rows)
         t_dec = time.perf_counter()
         logits, self.pools, state = self._decode_jit(
-            self.params, toks, pos, self.pools, ctx, state)
+            self.params, self._dev(toks), self._dev(pos), self.pools,
+            self._dev(ctx), state)
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
         # The decode step is the compute window in-flight DMAs hide in:
         # modeled width if configured, else measured wall time.
@@ -1881,10 +1906,10 @@ class ServingEngine:
         if not ops or self.pools is None:
             return
         pps = self.cache.pages_per_shard
-        src = jnp.asarray([s * pps + op.src_ppn for s, op in ops],
-                          jnp.int32)
-        dst = jnp.asarray([s * pps + op.dst_ppn for s, op in ops],
-                          jnp.int32)
+        src = self._dev(np.asarray([s * pps + op.src_ppn for s, op in ops],
+                                   np.int32))
+        dst = self._dev(np.asarray([s * pps + op.dst_ppn for s, op in ops],
+                                   np.int32))
         k, v = self.pools
         # pools are stacked [L, NP, ...]: compact every layer's pool.
         k = jax.vmap(lambda pool: kops.page_compact(
@@ -1896,14 +1921,19 @@ class ServingEngine:
 
     # ------------------------------------------------------------- states
 
-    def _stack_states(self, seqs):
+    def _stack_states(self, seqs, rows):
+        """Per-request decode states stacked into ``rows`` batch rows
+        (zeros for the idle ones)."""
         if not self.states:
             return {}
-        keys = self.states[seqs[0]].keys()
-        return {k: jnp.concatenate(
-            [self._state_of(s)[k] for s in seqs],
-            axis=1 if k in ("ssm", "conv", "cross_k", "cross_v") else 0)
-            for k in keys}
+        out = {}
+        for k in self.states[seqs[0]].keys():
+            parts = [self._state_of(s)[k] for s in seqs]
+            parts += [jnp.zeros_like(parts[0])] * (rows - len(seqs))
+            out[k] = jnp.concatenate(
+                parts,
+                axis=1 if k in ("ssm", "conv", "cross_k", "cross_v") else 0)
+        return out
 
     def _state_of(self, seq):
         return self.states[seq]
@@ -1913,7 +1943,8 @@ class ServingEngine:
             return
         for k, v in stacked.items():
             ax = 1 if k in ("ssm", "conv", "cross_k", "cross_v") else 0
-            parts = jnp.split(v, len(seqs), axis=ax)
+            # One row per request (prefill runs B=1); idle rows dropped.
+            parts = jnp.split(v, v.shape[ax], axis=ax)
             for s, part in zip(seqs, parts):
                 self.states[s][k] = part
 

@@ -218,13 +218,16 @@ class ShardedKVCache:
     # ---------------------------------------------------------------- pack
 
     def pack_ctx(self, seqs: Sequence[int], mpps: int,
-                 batch_sharded: bool = True) -> PageCtx:
+                 batch_sharded: bool = True,
+                 rows: Optional[int] = None) -> PageCtx:
         """Build the PageCtx for one decode step over ``seqs``.
 
         Call *after* ``append`` for the step's token.  mpps = max pages per
-        (sequence, shard).
+        (sequence, shard).  ``rows`` pads the batch with idle rows (no
+        page, write page -1).  The arrays are host-side numpy; the engine
+        commits them to its device with the step's other inputs.
         """
-        B, S = len(seqs), self.S
+        B, S = (rows or len(seqs)), self.S
         ptok = self.geo.page_tokens
         tables = np.full((B, S, mpps), -1, np.int32)
         ntok = np.zeros((B, S, mpps), np.int32)
@@ -254,8 +257,7 @@ class ShardedKVCache:
             local_vpn = len(table.ppn) - 1  # tail page just appended
             wpage[i, s] = table.ppn[local_vpn]
             wslot[i] = pos % ptok
-        return PageCtx(tables=jnp.asarray(tables), ntok=jnp.asarray(ntok),
-                       wpage=jnp.asarray(wpage), wslot=jnp.asarray(wslot),
+        return PageCtx(tables=tables, ntok=ntok, wpage=wpage, wslot=wslot,
                        batch_sharded=batch_sharded)
 
     def pack_dual(self, seqs: Sequence[int], shard: int, max_frames: int,

@@ -23,7 +23,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 BLOCK = 256
 
@@ -43,7 +42,7 @@ def _dequant(q: jax.Array, scale: jax.Array) -> jax.Array:
 
 def _ring_allreduce_q(x32: jax.Array, axis: str) -> jax.Array:
     """In-shard_map int8 ring all-reduce of a flat fp32 vector."""
-    n_dev = compat.axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     if n_dev == 1:
         return x32
     me = jax.lax.axis_index(axis)
@@ -92,7 +91,7 @@ def compressed_allreduce_flat(g32: jax.Array, err: jax.Array, axis: str):
 
     Error feedback: e' = (g + e) - Q(g + e) accumulated locally.
     """
-    n_dev = compat.axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     x = g32 + err
     q, s = _quant(x)
     xq = _dequant(q, s)

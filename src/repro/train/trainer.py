@@ -24,8 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro import compat
-from repro.compat import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -196,7 +194,7 @@ def _compress_grads(grads, ef, mesh, dp_axes):
                 fl, el, axis if isinstance(axis, str) else axis[0])
             return red, e_new
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False)
@@ -233,7 +231,7 @@ class Trainer:
                                 seed=seed)
 
         step_fn, init_fn = make_train_step(self.lm, hp, mesh)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             params, opt, ef = init_fn(jax.random.PRNGKey(seed))
             pspec, mspec = state_specs(params, hp, mesh)
             ospec = {"step": P(), "mu": mspec, "nu": mspec}
@@ -276,7 +274,7 @@ class Trainer:
         history = []
         pf = Prefetcher(self.data, start_step=self.start_step)
         try:
-            with compat.set_mesh(self.mesh):
+            with jax.sharding.set_mesh(self.mesh):
                 t0 = time.time()
                 for i in range(self.start_step, self.start_step + n_steps):
                     step, batch = next(pf)

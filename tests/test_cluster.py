@@ -690,3 +690,41 @@ def test_cluster_crashes_spill_steal_prestage_randomized():
     assert cluster.router.stats.crashes == 2
     assert cluster.router.stats.prestaged_requests > 0
     assert tier.stats["spilled_frames"] > 0
+
+
+_PLACEMENT = """
+import jax, numpy as np
+from repro.configs import get_smoke_config
+from repro.configs.base import PoolGeometry
+from repro.serving.cluster import ServingCluster
+from repro.serving.engine import Request
+devs = jax.devices()
+assert len(devs) == 4, devs
+cl = ServingCluster(get_smoke_config("qwen2.5-3b"),
+                    geometry=PoolGeometry(page_tokens=8, frame_pages=4),
+                    n_engines=4, max_batch=2, max_seq=64)
+for i, e in enumerate(cl.engines):
+    held = {d for x in jax.tree.leaves((e.params, e.pools))
+            for d in x.devices()}
+    assert held == {devs[i]}, (i, held)
+rng = np.random.default_rng(0)
+for i in range(6):
+    cl.submit(Request(rid=i, tenant=i % 2, max_new=3,
+                      prompt=rng.integers(0, 512, 12).astype(np.int32)))
+cl.run_until_drained()
+assert all(e.stats.decode_tokens > 0 for e in cl.engines)
+print("PLACED")
+"""
+
+
+def test_cluster_places_engine_i_on_device_i():
+    """On four (virtual CPU) devices each replica's weights and pools are
+    committed to its own device, and every replica serves from there."""
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "PLACED" in out.stdout

@@ -74,10 +74,12 @@ def test_fused_kernel_all_ready_bitwise_vs_baseline():
     baseline page-granularity kernel."""
     q, pk, pv, tables, ntok, scale = _case()
     base = paged_attention_kernel(q, pk, pv, tables, ntok,
-                                  granularity="page", scale=scale)
+                                  granularity="page", scale=scale,
+                                  interpret=True)
     slots = jnp.full(tables.shape, -1, jnp.int32)
     fused = fused_paged_attention_kernel(
-        q, pk, pv, pk[:2], pv[:2], tables, slots, ntok, scale=scale)
+        q, pk, pv, pk[:2], pv[:2], tables, slots, ntok, scale=scale,
+        interpret=True)
     for a, b in zip(fused, base):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
@@ -91,11 +93,12 @@ def test_fused_kernel_partial_matches_ref_and_gather():
     late[0, 0] = True                      # first block late on row 0
     sk, sv, dk, dv, slots = _stage_from_pool(pk, pv, tables, late)
     fused = fused_paged_attention_kernel(
-        q, dk, dv, sk, sv, tables, slots, ntok, scale=scale)
+        q, dk, dv, sk, sv, tables, slots, ntok, scale=scale, interpret=True)
     ref = fused_gather_attend_ref(q, dk, dv, sk, sv, tables, slots, ntok,
                                   scale=scale)
     base = paged_attention_kernel(q, pk, pv, tables, ntok,
-                                  granularity="page", scale=scale)
+                                  granularity="page", scale=scale,
+                                  interpret=True)
     for f, r, b in zip(fused, ref, base):
         np.testing.assert_allclose(np.asarray(f), np.asarray(r), **TOL)
         np.testing.assert_allclose(np.asarray(f), np.asarray(b), **TOL)
@@ -110,9 +113,10 @@ def test_fused_kernel_zero_resident_row():
     late[2, -1] = True                      # row 2 a single straggler
     sk, sv, dk, dv, slots = _stage_from_pool(pk, pv, tables, late)
     fused = fused_paged_attention_kernel(
-        q, dk, dv, sk, sv, tables, slots, ntok, scale=scale)
+        q, dk, dv, sk, sv, tables, slots, ntok, scale=scale, interpret=True)
     base = paged_attention_kernel(q, pk, pv, tables, ntok,
-                                  granularity="page", scale=scale)
+                                  granularity="page", scale=scale,
+                                  interpret=True)
     for f, b in zip(fused, base):
         np.testing.assert_allclose(np.asarray(f), np.asarray(b), **TOL)
 

@@ -43,7 +43,7 @@ def test_flash_attention_sweep(B, T, H, Hkv, dh, bq, bk, dtype):
     q = jnp.asarray(RNG.normal(size=(B, T, H, dh)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, T, Hkv, dh)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, T, Hkv, dh)), dtype)
-    out = flash_attention(q, k, v, causal=True, bq=bq, bk=bk)
+    out = flash_attention(q, k, v, causal=True, bq=bq, bk=bk, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32),
@@ -54,7 +54,7 @@ def test_flash_attention_noncausal():
     q = jnp.asarray(RNG.normal(size=(2, 128, 4, 32)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(2, 128, 4, 32)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(2, 128, 4, 32)), jnp.float32)
-    out = flash_attention(q, k, v, causal=False, bq=64, bk=64)
+    out = flash_attention(q, k, v, causal=False, bq=64, bk=64, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
@@ -119,9 +119,10 @@ def test_paged_attention_dual_sweep(B, H, n_kv, dh, dhv, ptok, fp,
     parts = [
         paged_attention_kernel(q, pool_k, pool_v, ft, fn,
                                granularity="frame", frame_pages=fp,
-                               scale=scale),
+                               scale=scale, interpret=True),
         paged_attention_kernel(q, pool_k, pool_v, pt, pn,
-                               granularity="page", scale=scale),
+                               granularity="page", scale=scale,
+                               interpret=True),
     ]
     o, m, l = combine_granularities(parts)
     out = o / np.maximum(np.asarray(l)[..., None], 1e-30)
@@ -154,7 +155,8 @@ def test_page_compact_sweep(NP, ptok, kv, dh, n, dtype):
     dst = perm[n:2 * n].astype(np.int32)
     src[n // 2] = -1
     dst[n // 2] = -1
-    out = page_compact(pool, jnp.asarray(src), jnp.asarray(dst))
+    out = page_compact(pool, jnp.asarray(src), jnp.asarray(dst),
+                       interpret=True)
     expect = ref.page_compact_ref(pool, jnp.asarray(src), jnp.asarray(dst))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
@@ -182,7 +184,7 @@ def test_ssd_scan_sweep(B, T, nh, hd, N, Q, with_h0, dtype):
     Cm = jnp.asarray(RNG.normal(size=(B, T, nh, N)), dtype)
     h0 = (jnp.asarray(RNG.normal(size=(B, nh, hd, N)), jnp.float32)
           if with_h0 else None)
-    y_k, h_k = ssd_scan(x, dt, A, Bm, Cm, chunk=Q, h0=h0)
+    y_k, h_k = ssd_scan(x, dt, A, Bm, Cm, chunk=Q, h0=h0, interpret=True)
     y_r, h_r = ref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk=Q, h0=h0)
     tol = dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16 else \
         dict(rtol=2e-4, atol=2e-4)

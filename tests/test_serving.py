@@ -330,3 +330,21 @@ def test_summary_pins_prestage_counters():
                     prestage_wasted=1, prestage_cancelled=1)
     assert "prestage 4 pages (2/1/1 hit/wasted/cancelled)" in s.summary()
     assert "prestage" not in EngineStats().summary()
+
+
+def test_engine_holds_params_in_model_dtype():
+    """Weights sit in ``cfg.dtype`` from construction on: no float32 copy
+    of the tree next to the serving one, and the model's per-call cast is
+    a no-op."""
+    import jax
+    import jax.numpy as jnp
+    eng = make_engine()
+    want = jnp.dtype(eng.cfg.dtype)
+    leaves = jax.tree.leaves(eng.params)
+    assert leaves and all(x.dtype == want for x in leaves
+                          if jnp.issubdtype(x.dtype, jnp.floating))
+    assert all(p.dtype == jnp.bfloat16 for p in eng.pools)
+    # A replica on the same device shares the weights' buffers: no copy.
+    twin = make_engine(params=eng.params)
+    assert all(a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()
+               for a, b in zip(leaves, jax.tree.leaves(twin.params)))

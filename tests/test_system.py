@@ -18,7 +18,7 @@ import pytest
 
 hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need hypothesis")
-from hypothesis import HealthCheck, given, settings
+from hypothesis import HealthCheck, example, given, settings
 from hypothesis import strategies as st
 
 from repro.core.baseline_mmu import BaselineMMU
@@ -110,6 +110,10 @@ def test_baseline_invariants_under_random_ops(ops):
 @settings(max_examples=40, deadline=None,
           suppress_health_check=[HealthCheck.too_slow])
 @given(ops=ops_strategy)
+# An en-masse allocation that runs out of memory part-way must leave no
+# half-mapped pages behind (found by this test's search).
+@example(ops=[("alloc", 0, 1)] * 8 + [("alloc", 0, 41)]
+         + [("alloc", 0, 48)] * 3 + [("alloc", 1, 33)])
 def test_cac_plans_stay_in_domain_and_disjoint(ops):
     """I9: every CAC copy batch has src∩dst=∅ and stays within one owner.
 
@@ -243,7 +247,8 @@ def test_compaction_preserves_payload_through_kernel():
     assert plan_ops, "expected a compaction plan"
     src = jnp.asarray([c.src_ppn for c in plan_ops], jnp.int32)
     dst = jnp.asarray([c.dst_ppn for c in plan_ops], jnp.int32)
-    out = np.asarray(page_compact(jnp.asarray(pool_arr), src, dst))
+    out = np.asarray(page_compact(jnp.asarray(pool_arr), src, dst,
+                                  interpret=True))
     # The virtual view through the updated table must be unchanged.
     t = mgr.table(0)
     for v, payload in view0.items():

@@ -201,13 +201,12 @@ def test_compressed_allreduce_single_device_exact():
     """On a 1-device axis the compressed all-reduce must be exact identity
     (and error feedback zero): the wire path is skipped."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.train.grad_compress import compressed_allreduce_flat
 
     mesh = jax.make_mesh((1,), ("data",))
     g = jnp.asarray(np.linspace(-1, 1, BLOCK), jnp.float32)
     e = jnp.zeros_like(g)
-    fn = shard_map(lambda a, b: compressed_allreduce_flat(a, b, "data"),
+    fn = jax.shard_map(lambda a, b: compressed_allreduce_flat(a, b, "data"),
                    mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
                    check_vma=False)
     red, err = fn(g, e)
