@@ -46,6 +46,7 @@ by ``(rid, shard, vpn)``); the frame table asserts double-placement.
 from __future__ import annotations
 
 import dataclasses
+from collections import deque
 from typing import Dict, Hashable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -1126,23 +1127,25 @@ class SharedHostTier:
 
 
 def aggregate_engine_stats(stats: Sequence[EngineStats]) -> EngineStats:
-    """Sum scalar counters (and merge the per-tier deadline dicts) of
-    several replicas into one cluster-wide :class:`EngineStats` — the
-    result supports the same ``summary()`` / ``slo_attainment()`` API."""
+    """Sum scalar counters, merge keyed counters (per-tier deadlines, per
+    span name) key by key and pool the samples of several replicas into
+    one cluster-wide :class:`EngineStats` — the result supports the same
+    ``summary()`` / ``slo_attainment()`` API."""
     agg = EngineStats()
     for st in stats:
         for f in dataclasses.fields(EngineStats):
             v = getattr(st, f.name)
-            if isinstance(v, list):
-                # Per-admission samples (admit_lat_us): concatenate so
-                # cluster-wide percentiles see every engine's tail.
-                getattr(agg, f.name).extend(v)
+            into = getattr(agg, f.name)
+            if isinstance(v, (list, deque)):
+                # Samples (admit_lat_us, the wait rings): pooled so
+                # cluster-wide percentiles see every engine's tail; a
+                # ring keeps its newest samples.
+                into.extend(v)
+            elif isinstance(v, dict):
+                for k, n in v.items():
+                    into[k] = into.get(k, 0) + n
             elif isinstance(v, (int, float)):
-                setattr(agg, f.name, getattr(agg, f.name) + v)
-        for tier, n in st.deadline_hits.items():
-            agg.deadline_hits[tier] = agg.deadline_hits.get(tier, 0) + n
-        for tier, n in st.deadline_misses.items():
-            agg.deadline_misses[tier] = agg.deadline_misses.get(tier, 0) + n
+                setattr(agg, f.name, into + v)
     return agg
 
 

@@ -103,6 +103,7 @@ from repro.serving.dma import (AsyncDMAEngine, DMAJob, Key, Prefetcher,
                                StagingBuffer)
 from repro.serving.host_tier import HostPageStore, PrefixIndex
 from repro.serving.kv_cache import ShardedKVCache
+from repro.serving.trace import WAIT_SAMPLES, Span, listen, spanned
 
 
 @functools.lru_cache(maxsize=None)
@@ -132,6 +133,17 @@ class Request:
     out: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     preemptions: int = 0
+    # perf_counter_ns stamps behind the measured waits: set by submit()
+    # (cleared by the first prefill) and by _preempt() (cleared by the
+    # resume).
+    submit_ns: Optional[int] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    preempt_ns: Optional[int] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+
+def _wait_ring() -> Deque[int]:
+    return deque(maxlen=WAIT_SAMPLES)
 
 
 @dataclasses.dataclass
@@ -140,7 +152,7 @@ class EngineStats:
     decode_steps: int = 0
     decode_tokens: int = 0
     compaction_copies: int = 0
-    wall_s: float = 0.0
+    wall_s: float = 0.0          # engine.step spans' total (s)
     coalesced_sum: float = 0.0   # running sum of per-step coalesced fraction
     occupancy_sum: float = 0.0
     # Host-tier demand paging (DESIGN.md §6).
@@ -170,8 +182,8 @@ class EngineStats:
     prefix_fault_us: float = 0.0    # modeled µs to fault reused prefixes in
     admit_hits: int = 0             # admissions via the suffix-prefill path
     admit_colds: int = 0            # admissions via the full-prefill path
-    admit_hit_us: float = 0.0       # wall µs spent in cache-hit admissions
-    admit_cold_us: float = 0.0      # wall µs spent in cold admissions
+    admit_hit_us: float = 0.0       # engine.prefill µs of cache hits
+    admit_cold_us: float = 0.0      # engine.prefill µs of cold admissions
     # Non-dense fallback (DESIGN.md §10): parks skipped because the model
     # family cannot replay cached KV (MoE routing / MLA latents / ssm
     # state) — counted instead of silently caching unreplayable pages.
@@ -232,6 +244,19 @@ class EngineStats:
     translation_walk_cycles: float = 0.0
     translation_queue_cycles: float = 0.0
     translation_us: float = 0.0
+    # Spans of the engine's host work (serving/trace.py), by name: count,
+    # total ns, self ns (total less its child spans), and the backend
+    # compiles made while it was the innermost open span.
+    span_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    span_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    span_self_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    compiles: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # Measured waits (ns), the newest WAIT_SAMPLES of each: submit() to
+    # the start of the request's first prefill, and _preempt() to the
+    # successful _resume() of the same request.
+    queue_wait_ns: Deque[int] = dataclasses.field(default_factory=_wait_ring)
+    resume_wait_ns: Deque[int] = dataclasses.field(
+        default_factory=_wait_ring)
 
     def note_deadline(self, priority: int, hit: bool) -> None:
         d = self.deadline_hits if hit else self.deadline_misses
@@ -485,6 +510,7 @@ class ServingEngine:
         self._foreign: set = set()
         self._stalled_steps = 0      # consecutive no-decode steps
         self.stats = EngineStats()
+        listen()
         # Async fault-in pipeline (DESIGN.md §7): DMA channel timeline +
         # double-buffered staging + next-step touch predictor.  The clock
         # is modeled µs: advanced by measured decode wall time (compute
@@ -529,8 +555,10 @@ class ServingEngine:
     # ------------------------------------------------------------- admission
 
     def submit(self, req: Request):
+        req.submit_ns = time.perf_counter_ns()
         self.queue.append(req)
 
+    @spanned("engine.admit")
     def _admit(self):
         # One admission order across resumes and new arrivals: highest
         # priority first; within a tier, tightest SLO deadline first
@@ -650,16 +678,19 @@ class ServingEngine:
         batched launch; returns per-page (k_page, v_page) payloads."""
         if not entries or self.pools is None:
             return []
-        pps = self.cache.pages_per_shard
-        gidx = self._dev(np.asarray([s * pps + ppn for s, _v, ppn in entries],
-                                    np.int32))
-        k, v = self.pools
-        kp = jax.vmap(lambda pool: kops.page_gather(
-            pool, gidx, use_pallas=self.use_pallas))(k)
-        vp = jax.vmap(lambda pool: kops.page_gather(
-            pool, gidx, use_pallas=self.use_pallas))(v)
-        kp, vp = np.asarray(kp), np.asarray(vp)       # [L, n, ptok, kv, dh]
-        return [(kp[:, i], vp[:, i]) for i in range(len(entries))]
+        with Span(self.stats, "tier.gather", pages=len(entries)):
+            pps = self.cache.pages_per_shard
+            gidx = self._dev(np.asarray(
+                [s * pps + ppn for s, _v, ppn in entries], np.int32))
+            k, v = self.pools
+            kp = jax.vmap(lambda pool: kops.page_gather(
+                pool, gidx, use_pallas=self.use_pallas))(k)
+            vp = jax.vmap(lambda pool: kops.page_gather(
+                pool, gidx, use_pallas=self.use_pallas))(v)
+            # The wait for the gathers and the device→host copy.
+            with Span(self.stats, "tier.to_host"):
+                kp, vp = np.asarray(kp), np.asarray(vp)  # [L, n, ptok, kv, dh]
+            return [(kp[:, i], vp[:, i]) for i in range(len(entries))]
 
     def _enqueue_outbound(self, keys: List[Tuple[int, int, int]],
                           entries: List[Tuple[int, int, int]],
@@ -691,33 +722,36 @@ class ServingEngine:
         """Swap a request out: frames → host store at base-page granularity,
         decode state retained host-side, pages freed for other tenants."""
         rid = victim.rid
-        # Pending compaction plans rewrote tables already; land the payload
-        # copies before gathering through those tables.
-        self._run_compaction()
-        pages = self.cache.mapped_pages(rid)     # [(shard, vpn, ppn)]
-        # A just-resumed victim may still hold non-resident pages whose
-        # payloads never left the host store — gather only resident ones
-        # (the rest keep their existing host copies).
-        resident = [
-            (s, vpn, ppn) for s, vpn, ppn in pages
-            if self.cache.mgrs[s].residency.resident[ppn]
-        ]
-        payloads = self._gather_pages(resident)
-        for (s, vpn, _ppn), (kp, vp) in zip(resident, payloads):
-            self.host.put(rid, s, vpn, kp, vp)
-        # The gather itself is outbound DMA traffic: it rides the
-        # channels' "out" lanes (hidden behind compute on a full-duplex
-        # link; contending with fault-ins when half-duplex).
-        self._enqueue_outbound([(rid, s, vpn) for s, vpn, _p in resident],
-                               resident, payloads, kind="evict")
-        self.cache.evict_pages(resident)
-        self._saved_tokens[rid] = self.cache.seq_tokens[rid]
-        self.cache.free(rid)
-        self.active.remove(victim)
-        victim.preemptions += 1
-        self.preempted.append(victim)
-        self.host.note_swap_out()
-        self.stats.swaps_out += 1
+        with Span(self.stats, "mm.preempt", rid=rid):
+            # Pending compaction plans rewrote tables already; land the
+            # payload copies before gathering through those tables.
+            self._run_compaction()
+            pages = self.cache.mapped_pages(rid)     # [(shard, vpn, ppn)]
+            # A just-resumed victim may still hold non-resident pages whose
+            # payloads never left the host store — gather only resident
+            # ones (the rest keep their existing host copies).
+            resident = [
+                (s, vpn, ppn) for s, vpn, ppn in pages
+                if self.cache.mgrs[s].residency.resident[ppn]
+            ]
+            payloads = self._gather_pages(resident)
+            for (s, vpn, _ppn), (kp, vp) in zip(resident, payloads):
+                self.host.put(rid, s, vpn, kp, vp)
+            # The gather itself is outbound DMA traffic: it rides the
+            # channels' "out" lanes (hidden behind compute on a full-duplex
+            # link; contending with fault-ins when half-duplex).
+            self._enqueue_outbound(
+                [(rid, s, vpn) for s, vpn, _p in resident],
+                resident, payloads, kind="evict")
+            self.cache.evict_pages(resident)
+            self._saved_tokens[rid] = self.cache.seq_tokens[rid]
+            self.cache.free(rid)
+            self.active.remove(victim)
+            victim.preemptions += 1
+            self.preempted.append(victim)
+            self.host.note_swap_out()
+            self.stats.swaps_out += 1
+        victim.preempt_ns = time.perf_counter_ns()
 
     def preempt(self, rid: int, *, hold: bool = False) -> bool:
         """Proactively swap an active request out (external-scheduler hook,
@@ -914,22 +948,27 @@ class ServingEngine:
         swapped-out payloads (DESIGN.md §12), the saved state is
         unusable — restart from the prompt instead.  The deterministic
         decoder makes the replay byte-identical to an unfaulted run."""
-        if self.host.take_lost(req.rid):
-            self._forget_request(req)
-            self.stats.lost_restarts += 1
-            return self._admit_one(req)
-        tokens = self._saved_tokens[req.rid]
-        if not self._alloc_with_preemption(req, tokens,
-                                           below_priority=req.priority):
-            return False
-        # Allocation under pressure may have planned compaction: execute the
-        # copies before anything reads the rewritten tables.
-        self._run_compaction()
-        self.cache.demote_host_backed(req.rid, self.host)
-        del self._saved_tokens[req.rid]
-        self.host.note_swap_in()
-        self.stats.swaps_in += 1
-        return True
+        with Span(self.stats, "mm.resume", rid=req.rid):
+            if self.host.take_lost(req.rid):
+                self._forget_request(req)
+                self.stats.lost_restarts += 1
+                return self._admit_one(req)
+            tokens = self._saved_tokens[req.rid]
+            if not self._alloc_with_preemption(req, tokens,
+                                               below_priority=req.priority):
+                return False
+            # Allocation under pressure may have planned compaction:
+            # execute the copies before anything reads the rewritten tables.
+            self._run_compaction()
+            self.cache.demote_host_backed(req.rid, self.host)
+            del self._saved_tokens[req.rid]
+            self.host.note_swap_in()
+            self.stats.swaps_in += 1
+            if req.preempt_ns is not None:
+                self.stats.resume_wait_ns.append(
+                    time.perf_counter_ns() - req.preempt_ns)
+                req.preempt_ns = None
+            return True
 
     def _forget_request(self, r: Request) -> None:
         """Erase every trace of a request whose saved payloads were lost
@@ -946,6 +985,7 @@ class ServingEngine:
         self.prefetch.stats["wasted_pages"] += dropped
         self.prefetch.cancel_seq(r.rid)
         self._saved_tokens.pop(r.rid, None)
+        r.preempt_ns = None
         r.out.clear()
         r.done = False
 
@@ -973,6 +1013,7 @@ class ServingEngine:
 
     # --------------------------------------------------- demand fault-in
 
+    @spanned("tier.fault_in")
     def _fault_in(self, seqs: List[int]) -> set:
         """touch() this step's pages; fault the missing ones in (blocking
         under ``fault_mode="sync"``, staged/overlapped under ``"async"``).
@@ -1317,6 +1358,7 @@ class ServingEngine:
 
     # --------------------------------------------- async prefetch pipeline
 
+    @spanned("tier.prefetch")
     def _drain_prefetches(self) -> None:
         """Step start: publish the transfers that completed during the
         previous decode into the staging front buffer (double-buffer
@@ -1358,6 +1400,7 @@ class ServingEngine:
     def _resume_order(self) -> List[int]:
         return [r.rid for r in self._resume_candidates()]
 
+    @spanned("tier.prefetch")
     def _issue_prefetch(self) -> None:
         """Step end (just before decode): issue the predicted next-step
         touches to the DMA channels so they transfer while we compute.
@@ -1441,24 +1484,30 @@ class ServingEngine:
         promote stall a cache hit paid to bring spilled prefix frames
         back (DESIGN.md §11) — the wall-µs counters stay, but on CPU
         they measure jit time, not the serving effect."""
-        t0 = time.perf_counter()
         T = len(req.prompt)
-        match = self._match_prefix(req)
-        promote_us = self._prefill_suffix(req, match) if match else None
+        with Span(self.stats, "engine.prefill", rid=req.rid) as sp:
+            if req.submit_ns is not None:
+                # A first admission: its wait in the queue ends here.
+                self.stats.queue_wait_ns.append(sp.t0 - req.submit_ns)
+                req.submit_ns = None
+            match = self._match_prefix(req)
+            promote_us = self._prefill_suffix(req, match) if match else None
+            if promote_us is None:
+                if match:
+                    # The matched payloads were quarantined mid-admission
+                    # (§12): fall back to full prefill — the prefix will be
+                    # re-derived (re-parked) when this request completes.
+                    self.stats.prefix_rederives += 1
+                self._prefill_full(req)
+            sp.set(hit=promote_us is not None)
         if promote_us is not None:
             self.stats.admit_hits += 1
-            self.stats.admit_hit_us += (time.perf_counter() - t0) * 1e6
+            self.stats.admit_hit_us += sp.ns / 1e3
             model_us = (T - len(match) * self.geo.page_tokens) \
                 * self.prefill_us_per_token + promote_us
         else:
-            if match:
-                # The matched payloads were quarantined mid-admission
-                # (§12): fall back to full prefill — the prefix will be
-                # re-derived (re-parked) when this request completes.
-                self.stats.prefix_rederives += 1
-            self._prefill_full(req)
             self.stats.admit_colds += 1
-            self.stats.admit_cold_us += (time.perf_counter() - t0) * 1e6
+            self.stats.admit_cold_us += sp.ns / 1e3
             model_us = T * self.prefill_us_per_token
         self.stats.admit_lat_us.append(model_us)
 
@@ -1520,7 +1569,8 @@ class ServingEngine:
             last_pos=self._dev(np.asarray([T - 1 - P], np.int32)),
             prefix_kv=self._dev((pk, pv)), prefix_len=P)
         self.states[req.rid] = state
-        req.out.append(int(jnp.argmax(logits[0])))
+        with Span(self.stats, "device.wait"):
+            req.out.append(int(jnp.argmax(logits[0])))
         self.stats.prefill_tokens += T - P      # compute actually done
         self.stats.prefix_reused_tokens += P
         # Admission-time fault-in through the async pipeline: the first
@@ -1561,6 +1611,7 @@ class ServingEngine:
                 self.stats.prefix_fault_us += job.transfer_us
         return promote_us
 
+    @spanned("tier.park")
     def _park_prefix(self, req: Request) -> None:
         """Completion hook (DESIGN.md §8): park the finished request's
         full prompt pages in the prefix index so future admissions
@@ -1659,7 +1710,8 @@ class ServingEngine:
             self.params, self._dev(batch), self.pools, self._dev(ctx),
             last_pos=self._dev(np.asarray([T - 1], np.int32)))
         self.states[req.rid] = state
-        nxt = int(jnp.argmax(logits[0]))
+        with Span(self.stats, "device.wait"):
+            nxt = int(jnp.argmax(logits[0]))
         req.out.append(nxt)
         # tokens beyond T within the padded page are unused; tracked length
         # stays T (+1 for the decode append below).
@@ -1681,6 +1733,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------- stepping
 
+    @spanned("mm.grow")
     def _append_with_preemption(self) -> List[Request]:
         """Grow active requests by one token slot, highest priority first.
 
@@ -1716,10 +1769,17 @@ class ServingEngine:
     def step(self):
         """One engine iteration as a two-stage pipeline: drain completed
         prefetches → admit → fault remaining misses (exposed) → decode
-        while the next step's prefetch is in flight → retire."""
+        while the next step's prefetch is in flight → retire.  The whole
+        iteration is the ``engine.step`` span; ``wall_s`` is their total."""
         if not self.alive:
             return False        # a crashed engine does no work (§12)
-        t0 = time.perf_counter()
+        st = self.stats
+        with Span(st, "engine.step", step=st.span_n.get("engine.step", 0)):
+            busy = self._step()
+        st.wall_s = st.span_ns["engine.step"] / 1e9
+        return busy
+
+    def _step(self) -> bool:
         # Advance the host tier's write-back pipeline to the engine clock
         # (DESIGN.md §11): frames whose spill completed during previous
         # steps persist now, freeing write-back queue slots before this
@@ -1732,7 +1792,6 @@ class ServingEngine:
             self._drain_prefetches()
         self._admit()
         if not self.active:
-            self.stats.wall_s += time.perf_counter() - t0
             return False
         # Append this step's token slot, then pack tables.
         runnable = self._append_with_preemption()
@@ -1749,8 +1808,8 @@ class ServingEngine:
                     f"{sorted(r.rid for r in self.active)} cannot grow "
                     f"(pool too small or fragmentation unrecoverable)")
             # Stalled steps still did real work (admission attempts, forced
-            # preemption gathers) — keep them in the tok/s denominator.
-            self.stats.wall_s += time.perf_counter() - t0
+            # preemption gathers) — their engine.step spans stay in the
+            # tok/s denominator.
             return bool(self.active or self.queue or self.preempted)
         self._stalled_steps = 0
         seqs = [r.rid for r in runnable]
@@ -1773,7 +1832,6 @@ class ServingEngine:
                     # No decode window opens: settle collected jobs and
                     # land staged payloads at the current clock.
                     self._settle_fused()
-                self.stats.wall_s += time.perf_counter() - t0
                 return bool(self.active or self.queue or self.preempted)
         # Decode always runs max_batch rows; idle rows read no page and
         # write nowhere.  One compiled step, and each row's numerics stay
@@ -1781,28 +1839,31 @@ class ServingEngine:
         # matmul's rounding can depend on its row count, which would let
         # the memory manager's scheduling change the tokens.
         rows = self.max_batch
-        ctx = self._ctx_global(self.cache.pack_ctx(seqs, self.mpps,
-                                                   rows=rows))
-        if self.fault_mode == "fused":
-            # Start-decode-on-resident: hand the kernel this step's
-            # staged arrivals + readiness mask instead of stalling for
-            # them (DESIGN.md §13).
-            ctx = self._attach_staging(ctx)
+        with Span(self.stats, "engine.pack"):
+            ctx = self._ctx_global(self.cache.pack_ctx(seqs, self.mpps,
+                                                       rows=rows))
+            if self.fault_mode == "fused":
+                # Start-decode-on-resident: hand the kernel this step's
+                # staged arrivals + readiness mask instead of stalling for
+                # them (DESIGN.md §13).
+                ctx = self._attach_staging(ctx)
+            toks = np.zeros(rows, np.int32)
+            pos = np.zeros(rows, np.int32)
+            for i, r in enumerate(runnable):
+                toks[i] = r.out[-1]
+                pos[i] = self.cache.seq_tokens[r.rid] - 1
+            state = self._stack_states(seqs, rows)
         if self.fault_mode in ("async", "fused"):
             # Stage 2: predicted next-step touches ride the DMA channels
             # while the decode below computes — their µs become hidden.
             self._issue_prefetch()
-        toks = np.zeros(rows, np.int32)
-        pos = np.zeros(rows, np.int32)
-        for i, r in enumerate(runnable):
-            toks[i] = r.out[-1]
-            pos[i] = self.cache.seq_tokens[r.rid] - 1
-        state = self._stack_states(seqs, rows)
         t_dec = time.perf_counter()
-        logits, self.pools, state = self._decode_jit(
-            self.params, self._dev(toks), self._dev(pos), self.pools,
-            self._dev(ctx), state)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with Span(self.stats, "model.decode"):
+            logits, self.pools, state = self._decode_jit(
+                self.params, self._dev(toks), self._dev(pos), self.pools,
+                self._dev(ctx), state)
+        with Span(self.stats, "device.wait"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
         # The decode step is the compute window in-flight DMAs hide in:
         # modeled width if configured, else measured wall time.
         self._clock_us += (self.decode_window_us
@@ -1833,33 +1894,36 @@ class ServingEngine:
                     # tier (DESIGN.md §10).
                     self.stats.note_deadline(
                         r.priority, self._clock_us <= r.deadline_us)
-        for r in done_now:
-            # Park the finished prompt's pages in the prefix cache before
-            # the frames are freed / host copies dropped (DESIGN.md §8).
-            self._park_prefix(r)
-            if self.translation_meter is not None:
-                # Address space retires with the sequence: its coalesced
-                # entries and in-flight MSHR keys go with it.
-                for s in range(self.cache.S):
-                    self.translation_meter.drop_space((r.rid, s))
-            self.active.remove(r)
-            self.cache.free(r.rid)
-            self.states.pop(r.rid, None)
-            self.host.drop_seq(r.rid)
-            self._note_prestage_waste(r.rid)
-            dropped = self.staging.invalidate_seq(r.rid)
-            self.stats.prefetch_wasted += dropped
-            self.prefetch.stats["wasted_pages"] += dropped
-            self.prefetch.cancel_seq(r.rid)
-            self._saved_tokens.pop(r.rid, None)
+        with Span(self.stats, "engine.retire"):
+            for r in done_now:
+                self._retire(r)
         # Execute any CAC compaction plans on-device.
         self._run_compaction()
         st = self.cache.stats()
         self.stats.coalesced_sum += st.get("coalesced_fraction", 0.0)
         self.stats.occupancy_sum += st.get("occupancy", 0.0)
         self.stats.decode_steps += 1
-        self.stats.wall_s += time.perf_counter() - t0
         return True
+
+    def _retire(self, r: Request) -> None:
+        # Park the finished prompt's pages in the prefix cache before
+        # the frames are freed / host copies dropped (DESIGN.md §8).
+        self._park_prefix(r)
+        if self.translation_meter is not None:
+            # Address space retires with the sequence: its coalesced
+            # entries and in-flight MSHR keys go with it.
+            for s in range(self.cache.S):
+                self.translation_meter.drop_space((r.rid, s))
+        self.active.remove(r)
+        self.cache.free(r.rid)
+        self.states.pop(r.rid, None)
+        self.host.drop_seq(r.rid)
+        self._note_prestage_waste(r.rid)
+        dropped = self.staging.invalidate_seq(r.rid)
+        self.stats.prefetch_wasted += dropped
+        self.prefetch.stats["wasted_pages"] += dropped
+        self.prefetch.cancel_seq(r.rid)
+        self._saved_tokens.pop(r.rid, None)
 
     def _meter_translation(self, runnable) -> None:
         """Run this step's packed KV page touches through the translation
@@ -1905,18 +1969,19 @@ class ServingEngine:
                         (owner_vpn[0], s), owner_vpn[1])
         if not ops or self.pools is None:
             return
-        pps = self.cache.pages_per_shard
-        src = self._dev(np.asarray([s * pps + op.src_ppn for s, op in ops],
-                                   np.int32))
-        dst = self._dev(np.asarray([s * pps + op.dst_ppn for s, op in ops],
-                                   np.int32))
-        k, v = self.pools
-        # pools are stacked [L, NP, ...]: compact every layer's pool.
-        k = jax.vmap(lambda pool: kops.page_compact(
-            pool, src, dst, use_pallas=self.use_pallas))(k)
-        v = jax.vmap(lambda pool: kops.page_compact(
-            pool, src, dst, use_pallas=self.use_pallas))(v)
-        self.pools = (k, v)
+        with Span(self.stats, "mm.compact"):
+            pps = self.cache.pages_per_shard
+            src = self._dev(np.asarray(
+                [s * pps + op.src_ppn for s, op in ops], np.int32))
+            dst = self._dev(np.asarray(
+                [s * pps + op.dst_ppn for s, op in ops], np.int32))
+            k, v = self.pools
+            # pools are stacked [L, NP, ...]: compact every layer's pool.
+            k = jax.vmap(lambda pool: kops.page_compact(
+                pool, src, dst, use_pallas=self.use_pallas))(k)
+            v = jax.vmap(lambda pool: kops.page_compact(
+                pool, src, dst, use_pallas=self.use_pallas))(v)
+            self.pools = (k, v)
         self.stats.compaction_copies += len(ops)
 
     # ------------------------------------------------------------- states

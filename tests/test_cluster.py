@@ -334,6 +334,34 @@ def test_cluster_stats_aggregation():
     assert agg.slo_attainment() == pytest.approx(2 / 3)
 
 
+def test_cluster_stats_aggregation_merges_spans_and_wait_rings():
+    """Span counters merge name by name, and the wait rings pool every
+    engine's samples, keeping the ring's bound."""
+    from repro.serving.trace import WAIT_SAMPLES
+    a, b = EngineStats(), EngineStats()
+    a.span_n.update({"engine.step": 2, "mm.preempt": 1})
+    b.span_n.update({"engine.step": 3})
+    a.span_ns.update({"engine.step": 50, "mm.preempt": 20})
+    b.span_ns.update({"engine.step": 70})
+    a.span_self_ns.update({"engine.step": 30, "mm.preempt": 20})
+    b.span_self_ns.update({"engine.step": 70})
+    a.compiles.update({"engine.prefill": 2})
+    b.compiles.update({"engine.prefill": 1, "model.decode": 1})
+    a.queue_wait_ns.extend([5, 6])
+    b.queue_wait_ns.extend([7])
+    b.resume_wait_ns.extend(range(WAIT_SAMPLES))
+    a.resume_wait_ns.append(-1)
+    agg = aggregate_engine_stats([a, b])
+    assert agg.span_n == {"engine.step": 5, "mm.preempt": 1}
+    assert agg.span_ns == {"engine.step": 120, "mm.preempt": 20}
+    assert agg.span_self_ns == {"engine.step": 100, "mm.preempt": 20}
+    assert agg.compiles == {"engine.prefill": 3, "model.decode": 1}
+    assert list(agg.queue_wait_ns) == [5, 6, 7]
+    # a's one sample came first, so b's full ring pushes it out.
+    assert list(agg.resume_wait_ns) == list(range(WAIT_SAMPLES))
+    assert a.span_n == {"engine.step": 2, "mm.preempt": 1}   # inputs kept
+
+
 # ------------------------------------------------- MoE/MLA park fallback
 
 
