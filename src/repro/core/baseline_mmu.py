@@ -19,7 +19,7 @@ so every engine/benchmark can flip between managers.
 from __future__ import annotations
 
 import heapq
-from typing import Dict, List, Sequence, Set, Tuple
+from typing import Collection, Dict, List, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -124,6 +124,13 @@ class BaselineMMU:
                 self.coalesce_opportunities += int(ok)
             self.seq_tokens[owner] = tok + 1
         return new_vpns
+
+    def capacity_if_released(self, owners: Collection[int]) -> int:
+        """Most pages a fresh owner could map if ``owners`` released
+        theirs: the free pages plus theirs (any free page will do)."""
+        return self.config.num_pages - sum(
+            len(t.mapped_vpns()) for o, t in self.tables.items()
+            if o not in owners)
 
     # -- deallocation -----------------------------------------------------------------
 

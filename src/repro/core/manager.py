@@ -12,7 +12,7 @@ benchmarks can swap managers with one flag.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -97,6 +97,17 @@ class MosaicManager:
                 new_vpns.append(vpn)
             self.seq_tokens[owner] = tok + 1
         return new_vpns
+
+    def capacity_if_released(self, owners: Collection[int]) -> int:
+        """Most pages a fresh owner could map if ``owners`` released
+        theirs: an upper bound on what allocation can find, in whole
+        frames.  The soft guarantee keeps each frame to one owner, so every
+        remaining owner holds at least ``ceil(pages / frame_pages)`` frames
+        however far CAC compacts it."""
+        fp = self.config.frame_pages
+        held = sum(-(-len(t.mapped_vpns()) // fp)
+                   for o, t in self.tables.items() if o not in owners)
+        return (self.config.num_frames - held) * fp
 
     def _with_compaction_retry(self, owner: int, fn):
         try:

@@ -467,6 +467,9 @@ class LeasedStoreView:
     def take_lost(self, seq: int) -> bool:
         return False if self.tier is None else self.tier.take_lost(seq)
 
+    def is_lost(self, seq: int) -> bool:
+        return False if self.tier is None else self.tier.is_lost(seq)
+
     def pump(self, now_us: float) -> None:
         if self.tier is not None:
             self.tier.pump(now_us)
@@ -666,6 +669,10 @@ class SharedHostTier:
             self.lost_seqs.discard(seq)
             return True
         return False
+
+    def is_lost(self, seq: int) -> bool:
+        """:meth:`take_lost` without clearing the flag."""
+        return seq in self.lost_seqs
 
     # --------------------------------------------------------- view hooks
 

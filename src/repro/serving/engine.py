@@ -163,6 +163,9 @@ class EngineStats:
     transfer_us: float = 0.0
     swaps_out: int = 0           # whole-request preemptions
     swaps_in: int = 0            # whole-request resumes
+    # Admissions the fit test refused without evicting anyone: the
+    # candidate could not fit even with every eligible victim gone.
+    evictions_declined: int = 0
     # Async fault-in pipeline (DESIGN.md §7).
     fault_exposed_us: float = 0.0   # transfer µs the engine stalled on
     fault_hidden_us: float = 0.0    # transfer µs overlapped with decode
@@ -321,7 +324,8 @@ class EngineStats:
             f"{self.prefetch_wasted} hit/miss/wasted | "
             f"out {self.evict_pages} pages in {self.evict_dmas} DMAs "
             f"({self.bytes_out / 1024:.0f} KiB) | "
-            f"swaps {self.swaps_out}/{self.swaps_in}")
+            f"swaps {self.swaps_out}/{self.swaps_in} "
+            f"({self.evictions_declined} declined)")
         if self.prefix_hits or self.prefix_misses:
             line += (f" | prefix {self.prefix_hits}/{self.prefix_misses} "
                      f"hit/miss ({self.prefix_reused_tokens} tok reused)")
@@ -589,15 +593,30 @@ class ServingEngine:
                 # strictness makes displacement chains terminate).  With a
                 # full batch and no displaceable victim, no lower-priority
                 # candidate can enter either — stop the round.
+                # Nor does it displace anyone for a candidate that memory
+                # could not hold with every eligible victim gone.
                 victim = self._pick_victim(below_priority=cand.priority)
                 if victim is None:
                     break
+                # A resume whose payloads were lost restarts from its
+                # prompt (_resume), so that is all it allocates.
+                need = (self._saved_tokens[cand.rid]
+                        if resume and not self.host.is_lost(cand.rid)
+                        else self._prompt_tokens(cand))
+                if not self._could_fit(cand, need,
+                                       below_priority=cand.priority):
+                    self.stats.evictions_declined += 1
+                    skipped.add(cand.rid)
+                    continue
                 self._preempt(victim)
             ok = self._resume(cand) if resume else self._admit_one(cand)
             if not ok:
                 # Memory can't fit this candidate right now; a smaller or
-                # lower-priority one may still fill the idle capacity (and
-                # any victims it preempted in vain resume right here).
+                # lower-priority one may still fill the idle capacity.
+                # The fit test evicts nobody for a candidate that could
+                # not fit with every victim gone, so a victim preempted
+                # for it in vain (the test is necessary, not sufficient)
+                # is rare; it resumes right here.
                 skipped.add(cand.rid)
                 continue
             (self.preempted if resume else self.queue).remove(cand)
@@ -618,6 +637,13 @@ class ServingEngine:
         return (float(self.cache.resident_page_count(r.rid))
                 * (r.priority + 1) * remaining)
 
+    def _eligible_victims(self, below_priority: Optional[int],
+                          exclude: Tuple[int, ...]) -> List[Request]:
+        """Active requests a victim may be picked from: below
+        ``below_priority`` (when given) and not in ``exclude``."""
+        return [r for r in self.active if r.rid not in exclude
+                and (below_priority is None or r.priority < below_priority)]
+
     def _pick_victim(self, *, below_priority: Optional[int] = None,
                      exclude: Tuple[int, ...] = ()) -> Optional[Request]:
         """Cheapest-to-evict active request under the configured policy.
@@ -627,9 +653,7 @@ class ServingEngine:
         respect ``below_priority`` (a candidate never displaces its own
         tier or above at admission) and tie-break youngest-first.
         """
-        cands = [r for r in self.active if r.rid not in exclude]
-        if below_priority is not None:
-            cands = [r for r in cands if r.priority < below_priority]
+        cands = self._eligible_victims(below_priority, exclude)
         if not cands:
             return None
         if self.victim_policy == "priority":
@@ -638,39 +662,63 @@ class ServingEngine:
                    key=lambda r: (self._victim_score(r), r.priority, -r.rid))
 
     def _alloc_with_preemption(self, req: Request, n_tokens: int, *,
-                               below_priority: Optional[int],
-                               exclude: Tuple[int, ...] = ()) -> bool:
+                               below_priority: Optional[int]) -> bool:
         """Allocate with growth headroom, preempting victims as needed.
 
-        The growth guard is part of the loop: when an allocation succeeds
-        but would leave no room for one decode step of the batch, another
-        victim is evicted and the allocation retried — so victims are only
-        ever swapped out on a path that ends in admission, never stranded
-        by a post-hoc guard failure.  Returns False (leaving ``req``
-        unallocated) when no victim remains.
+        Victims go one at a time, cheapest first, and the allocation is
+        retried after each.  The growth guard is part of the loop: an
+        allocation that would leave no room for one decode step of the
+        batch evicts another victim too, so no victim is stranded by a
+        post-hoc guard failure.  Before the first eviction the fit test
+        (:meth:`_could_fit`) asks whether ``req`` could be placed at all
+        with every eligible victim gone; if not, nobody is evicted
+        (``evictions_declined``) — each eviction would pay a device→host
+        copy for an admission that fails anyway, and the victims would
+        resume in the same round.  Returns False (leaving ``req``
+        unallocated) when the test fails or no victim remains.
         """
+        tested = False
         while True:
             try:
                 self.cache.allocate(req.rid, n_tokens)
             except OutOfMemory:
-                # Roll back the partial allocation before retrying.
-                self.cache.free(req.rid)
-                victim = self._pick_victim(below_priority=below_priority,
-                                           exclude=exclude + (req.rid,))
-                if victim is None:
-                    return False
-                self._preempt(victim)
-                continue
-            if self._growth_guard_ok(req):
-                return True
-            # Allocated but starved of growth headroom (resume↔preempt
-            # livelock otherwise): evict one more victim and re-place.
+                pass
+            else:
+                if self._growth_guard_ok(req):
+                    return True
+            # Roll back the partial allocation, or the one starved of
+            # growth headroom (resume↔preempt livelock otherwise), and
+            # evict one more victim before re-placing.
             self.cache.free(req.rid)
             victim = self._pick_victim(below_priority=below_priority,
-                                       exclude=exclude + (req.rid,))
+                                       exclude=(req.rid,))
             if victim is None:
                 return False
+            if not tested:
+                tested = True
+                if not self._could_fit(req, n_tokens,
+                                       below_priority=below_priority):
+                    self.stats.evictions_declined += 1
+                    return False
             self._preempt(victim)
+
+    def _could_fit(self, req: Request, n_tokens: int, *,
+                   below_priority: Optional[int]) -> bool:
+        """The fit test: could ``req`` take ``n_tokens`` and pass the
+        growth guard if every eligible victim left?  A necessary condition
+        for :meth:`_alloc_with_preemption` to end in admission, so it
+        never refuses one the loop would complete: the memory bound
+        assumes the victims' pages freed and compaction at its best (each
+        manager's :meth:`capacity_if_released`), and evicting every
+        victim leaves the most free pages for the fewest staying rows."""
+        victims = self._eligible_victims(below_priority, (req.rid,))
+        gone = [v.rid for v in victims]
+        if not self.cache.fits_if_released(n_tokens, gone):
+            return False
+        staying = len(self.active) - len(victims)
+        return staying == 0 or (self.cache.free_page_count(gone)
+                                - sum(self.cache.shard_pages(n_tokens))
+                                >= staying + 2)
 
     def _gather_pages(self, entries: List[Tuple[int, int, int]]
                       ) -> List[Tuple[np.ndarray, np.ndarray]]:
@@ -929,17 +977,13 @@ class ServingEngine:
             self._prestage_keys.pop(k, None)
             self.stats.prestage_wasted += 1
 
-    def _free_pages_total(self) -> int:
-        return sum(m.config.num_pages - int(m.pool.page_allocated.sum())
-                   for m in self.cache.mgrs)
-
     def _growth_guard_ok(self, req: Request) -> bool:
         """Admitting ``req`` must leave room for ≥ one decode step of the
         whole batch, or the newcomer would be preempted again before
         producing a token (resume↔preempt livelock)."""
         if not self.active:
             return True          # a sole request always fits (pool ≥ mpps)
-        return self._free_pages_total() >= len(self.active) + 2
+        return self.cache.free_page_count() >= len(self.active) + 2
 
     def _resume(self, req: Request) -> bool:
         """Re-map a preempted request; payloads fault in on next touch.
@@ -1000,12 +1044,15 @@ class ServingEngine:
             self.queue.appendleft(r)
             self.stats.lost_restarts += 1
 
-    def _admit_one(self, req: Request) -> bool:
-        ptok = self.geo.page_tokens
-        T = len(req.prompt)
+    def _prompt_tokens(self, req: Request) -> int:
+        """Tokens a fresh admission of ``req`` allocates: its prompt,
+        behind a VLM's frontend tokens."""
         n_prefix = (self.cfg.frontend_tokens
                     if self.cfg.family == "vlm" else 0)
-        if not self._alloc_with_preemption(req, n_prefix + T,
+        return n_prefix + len(req.prompt)
+
+    def _admit_one(self, req: Request) -> bool:
+        if not self._alloc_with_preemption(req, self._prompt_tokens(req),
                                            below_priority=req.priority):
             return False
         self._prefill(req)

@@ -172,6 +172,10 @@ class HostPageStore:
         (always False here — a private store has no disk underneath)."""
         return False
 
+    def is_lost(self, seq: int) -> bool:
+        """:meth:`take_lost` without clearing the flag (always False)."""
+        return False
+
 
 # ------------------------------------------------------------------- disk
 

@@ -21,7 +21,7 @@ lists and splintered page lists (``pack_dual``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Collection, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -186,6 +186,34 @@ class ShardedKVCache:
         ftok = self.geo.frame_pages * self.geo.page_tokens
         frames = (n_tokens + ftok - 1) // ftok
         return frames * self.geo.frame_pages
+
+    def shard_pages(self, n_tokens: int) -> List[int]:
+        """Pages a fresh allocation of ``n_tokens`` maps on each shard
+        (global frame ``f`` on shard ``f % S``)."""
+        fp = self.geo.frame_pages
+        pages = -(-n_tokens // self.geo.page_tokens)
+        out = [0] * self.S
+        for f in range(-(-pages // fp)):
+            out[f % self.S] += min(fp, pages - f * fp)
+        return out
+
+    def fits_if_released(self, n_tokens: int, seqs: Collection[int]
+                         ) -> bool:
+        """Whether a fresh allocation of ``n_tokens`` could succeed once
+        ``seqs`` released their pages, shard by shard.  A necessary
+        condition: each manager's bound counts the released pages free
+        and compaction at its best."""
+        return all(need <= m.capacity_if_released(seqs)
+                   for need, m in zip(self.shard_pages(n_tokens), self.mgrs))
+
+    def free_page_count(self, released: Collection[int] = ()) -> int:
+        """Unallocated pages over every shard, counting the pages of the
+        ``released`` sequences as free."""
+        return sum(
+            m.config.num_pages - int(m.pool.page_allocated.sum())
+            + sum(len(m.tables[q].mapped_vpns())
+                  for q in released if q in m.tables)
+            for m in self.mgrs)
 
     def resident_page_count(self, seq: int) -> int:
         """HBM-resident pages mapped by ``seq`` (the eviction-cost term

@@ -461,14 +461,14 @@ class RequestRouter:
         stealing would just ping-pong the request."""
         if len(src.active) >= src.max_batch:
             return True
-        return src._free_pages_total() < \
+        return src.cache.free_page_count() < \
             pages_needed + len(src.active) + 2
 
     def _dst_fits(self, dst: ServingEngine, pages_needed: int) -> bool:
         if len(dst.active) + len(dst.queue) + len(dst.preempted) \
                 >= dst.max_batch:
             return False
-        return dst._free_pages_total() >= \
+        return dst.cache.free_page_count() >= \
             pages_needed + len(dst.active) + 2
 
     def _steal(self) -> None:
