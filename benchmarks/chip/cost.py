@@ -1,4 +1,5 @@
-"""Operations and bytes the served model's calls need, and chip peaks.
+"""Operations and bytes the dense family's calls need, and the chip
+peaks and roofline share that every family shares.
 
 Computed from a configuration's shapes alone (``weights.Dims``), never
 from the program.  A matmul of an [m, k] by [k, n] matrix is 2·m·k·n
@@ -105,12 +106,6 @@ def page_bytes(dm: Dims, page_tokens: int) -> int:
     """One page of K or V across every layer."""
     return dm.n_layers * page_tokens * dm.n_kv_heads * dm.head_dim \
         * itemsize(dm)
-
-
-def mover_bytes(dm: Dims, page_tokens: int, pages: int) -> float:
-    """A gather, scatter or compaction of ``pages`` pages of one pool
-    (K or V) over all layers: each page read once and written once."""
-    return 2.0 * pages * page_bytes(dm, page_tokens)
 
 
 def roofline_share(flops: float, nbytes: float, seconds: float,

@@ -1,16 +1,19 @@
 """One benchmark cell: load, warm up, measure a window, check, report.
 
 The cell's configuration, traffic mix, limits and per-layer readers are
-files found by the names in ``BENCHMARK.json``; nothing here knows a
-particular cell.  The system under test is ``ServingEngine`` driven
-through its entry, ``submit`` and ``step``, with compiled Pallas page
-movers and the engine's defaults except for what the mix's ``engine``
-block sets.
+files found by the names in ``BENCHMARK.json``, and what a model family
+gives (sizes, ``ModelConfig``, weights, the work of each call) is a
+module of ``families/`` named by the configuration's ``family`` key;
+nothing here knows a particular cell or family.  The system under test
+is ``ServingEngine`` driven through its entry, ``submit`` and ``step``,
+with compiled Pallas page movers and the engine's defaults except for
+what the mix's ``engine`` block sets.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -27,13 +30,13 @@ import numpy as np
 import cost
 import devtrace
 import traffic
-import weights as W
 
 CHIP = pathlib.Path(__file__).resolve().parent
 ROOT = CHIP.parents[1]
+FAMILIES = CHIP / "families"
 sys.path.insert(0, str(ROOT / "src"))
 
-from repro.configs.base import ModelConfig, PoolGeometry  # noqa: E402
+from repro.configs.base import PoolGeometry  # noqa: E402
 from repro.kernels import ops as kops  # noqa: E402
 from repro.serving.engine import Request, ServingEngine  # noqa: E402
 
@@ -55,8 +58,33 @@ class Cell:
     chips: int
 
     @property
-    def dims(self) -> W.Dims:
-        return W.Dims.of(self.cfg)
+    def family(self):
+        return family_of(self.cfg)
+
+    @property
+    def dims(self):
+        return self.family.dims(self.cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: pathlib.Path):
+    """The module in ``path``, loaded once: a family's dims are the static
+    argument of jitted calls, so their class must stay one class."""
+    spec = importlib.util.spec_from_file_location(
+        f"{path.parent.name}_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_of(cfg: dict):
+    """The module ``families/<family>.py`` of the configuration's
+    ``family`` key, dense where it has none (``families/dense.py`` says
+    what a family module gives)."""
+    path = FAMILIES / f"{cfg.get('family', 'dense')}.py"
+    if not path.is_file():
+        raise SystemExit(f"no model family module {path}")
+    return _module(path)
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -83,14 +111,12 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         chips=int(w["chips"]))
 
 
-def model_config(name: str, dm: W.Dims, max_seq: int) -> ModelConfig:
-    return ModelConfig(
-        name=name, family="dense", n_layers=dm.n_layers,
-        d_model=dm.d_model, n_heads=dm.n_heads, n_kv_heads=dm.n_kv_heads,
-        d_ff=dm.d_ff, vocab_size=dm.vocab, head_dim=dm.head_dim,
-        qkv_bias=dm.qkv_bias, rope_theta=dm.rope_theta,
-        norm_eps=dm.norm_eps, tie_embeddings=dm.tied, max_seq_len=max_seq,
-        dtype=dm.dtype)
+def served_model(cell: Cell, seed: int):
+    """The program's ``ModelConfig`` for ``cell`` and its weights from
+    ``seed``, as the cell's family makes them."""
+    fam, dm = cell.family, cell.dims
+    return (fam.model_config(cell.name, dm, cell.mix["engine"]["max_seq"]),
+            fam.model_weights(seed, dm))
 
 
 def engine_kwargs(mix: dict) -> dict:
@@ -307,11 +333,27 @@ def instrument(eng: ServingEngine):
         lambda p, batch, pools, ctx, last_pos=None, prefix_kv=None,
         prefix_len=0: (last_pos, prefix_len))
     kops_orig = {m: getattr(kops, m) for m in MOVERS}
-    pages = {"page_gather": lambda pool, idx, **kw: idx.shape[0],
-             "page_scatter": lambda pool, idx, pages, **kw: idx.shape[0],
-             "page_compact": lambda pool, src, dst, **kw: src.shape[0]}
+    pages = {"page_gather": lambda idx, **kw: idx.shape[0],
+             "page_scatter": lambda idx, pages, **kw: idx.shape[0],
+             "page_compact": lambda src, dst, **kw: src.shape[0]}
+    # Each mover is called on every pool in turn, K and then V (the
+    # engine's ``_gather_pages``, ``_scatter_pages`` and ``_compact``), so
+    # a call's place in that cycle names its pool; the one layer's pool it
+    # is given must have that pool's shape.
+    shapes = [p.shape[1:] for p in eng.pools or ()]
+
+    def moved(m):
+        def note(pool, *a, **kw):
+            i = len(log[m]) % len(shapes)
+            if pool.shape != shapes[i]:
+                raise RuntimeError(
+                    f"{m} call {len(log[m])} was given a pool of shape "
+                    f"{pool.shape}, not pool {i}'s {shapes[i]}")
+            return i, pages[m](*a, **kw)
+        return note
+
     for m in MOVERS:
-        setattr(kops, m, spanned(kops_orig[m], m, pages[m]))
+        setattr(kops, m, spanned(kops_orig[m], m, moved(m)))
     for name in devtrace.ENGINE_SPANS:
         if hasattr(eng, name):
             setattr(eng, name, spanned(getattr(eng, name), name))
@@ -324,21 +366,23 @@ def instrument(eng: ServingEngine):
     return log, restore
 
 
-def call_work(log: Dict[str, list], dm: W.Dims, page_tokens: int) -> dict:
-    """Operations and bytes of the logged calls, by the cost functions."""
+def call_work(log: Dict[str, list], fam, dm, page_tokens: int) -> dict:
+    """Operations and bytes of the logged calls, by the cost functions of
+    the family ``fam``."""
     dec_f = dec_b = 0.0
     for ntok in log["decode"]:
         ctx = np.asarray(ntok).reshape(np.shape(ntok)[0], -1).sum(axis=1)
         ctx = ctx[ctx > 0]
-        dec_f += cost.decode_flops(dm, ctx)
-        dec_b += cost.decode_bytes(dm, ctx)
+        dec_f += fam.decode_flops(dm, ctx)
+        dec_b += fam.decode_bytes(dm, ctx)
     pre_f = 0.0
     for last_pos, prefix_len in log["prefill"]:
-        pre_f += cost.prefill_flops(dm, int(np.asarray(last_pos)[0]) + 1
-                                    + prefix_len)
-    # One kernel call moves the pages of one pool (K or V), all layers.
-    mov_b = sum(cost.mover_bytes(dm, page_tokens, n)
-                for m in MOVERS for n in log[m])
+        pre_f += fam.prefill_flops(dm, int(np.asarray(last_pos)[0]) + 1
+                                   + prefix_len)
+    # One kernel call moves pages of one pool, all layers: each page read
+    # once and written once.
+    page = fam.pool_page_bytes(dm, page_tokens)
+    mov_b = sum(2.0 * n * page[pool] for m in MOVERS for pool, n in log[m])
     return {"decode_flops": dec_f, "decode_bytes": dec_b,
             "prefill_flops": pre_f, "mover_bytes": mov_b,
             "decode_calls": len(log["decode"]),
@@ -440,11 +484,7 @@ def passed(checks: Dict[str, dict]) -> bool:
 
 
 def read_metric(name: str, ctx: dict) -> Optional[float]:
-    path = CHIP / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    v = mod.read(ctx)
+    v = _module(CHIP / "metrics" / f"{name}.py").read(ctx)
     return None if v is None else float(v)
 
 
@@ -471,8 +511,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     compiles = Compiles()
     dm = cell.dims
     e_kw = engine_kwargs(cell.mix)
-    mcfg = model_config(cell.name, dm, cell.mix["engine"]["max_seq"])
-    params = W.model_weights(seed, dm)
+    mcfg, params = served_model(cell, seed)
     mem("weights")
     warm = ServingEngine(mcfg, params=params, **e_kw)
     warm_up(warm, cell, seed)
@@ -507,7 +546,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     ctx = {"cell": cell, "dims": dm, "sent": sent, "seconds": seconds,
            "stats": stats, "e2e": e2e, "compiles_in_window": in_window,
            "trace": reduced, "peaks": pk,
-           "work": (call_work(log, dm, e_kw["geometry"].page_tokens)
+           "work": (call_work(log, cell.family, dm,
+                              e_kw["geometry"].page_tokens)
                     if trace else None)}
     del eng, params
     gc.collect()

@@ -34,7 +34,6 @@ def main(argv=None) -> int:
     import jax
     import harness
     import traffic
-    import weights as W
     from repro.serving.engine import ServingEngine
 
     root = pathlib.Path.cwd()
@@ -45,9 +44,7 @@ def main(argv=None) -> int:
     cell = harness.load_cell(args.workload, root)
     dm = cell.dims
     kw = harness.engine_kwargs(cell.mix)
-    mcfg = harness.model_config(args.workload, dm,
-                                cell.mix["engine"]["max_seq"])
-    params = W.model_weights(args.seed, dm)
+    mcfg, params = harness.served_model(cell, args.seed)
     warm = ServingEngine(mcfg, params=params, **kw)
     harness.warm_up(warm, cell, args.seed)
     del warm

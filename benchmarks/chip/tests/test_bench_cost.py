@@ -1,31 +1,83 @@
-"""The benchmark's cost functions, peaks table and seeded weights."""
+"""The benchmark's cost functions, peaks table and seeded weights, for
+each configuration through its family."""
 
 import json
 import pathlib
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import cost
+import harness
 import weights as W
+from repro.models.common import cast
+from repro.models.lm import LM
 
 CHIP = pathlib.Path(__file__).resolve().parents[1]
 CONFIGS = sorted((CHIP / "configs").glob("*.json"))
 
 
+def _cfg(path):
+    return json.loads(path.read_text())
+
+
 def _dims(path):
-    return W.Dims.of(json.loads(path.read_text()))
+    cfg = _cfg(path)
+    return harness.family_of(cfg).dims(cfg)
 
 
-@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+DENSE = [p for p in CONFIGS
+         if harness.family_of(_cfg(p)) is harness.family_of({})]
+
+
+@pytest.mark.parametrize("path", DENSE, ids=lambda p: p.stem)
 def test_matrix_bytes_equal_the_programs_param_count(path):
-    import harness
     dm = _dims(path)
-    mcfg = harness.model_config(path.stem, dm, 2048)
+    mcfg = harness.family_of({}).model_config(path.stem, dm, 2048)
     assert cost.matrix_params(dm) * 2 == mcfg.param_count() * 2
     assert cost.weight_bytes(dm) == 2 * (mcfg.param_count()
                                          + cost.vector_params(dm))
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_family_weights_are_the_programs_tree(path):
+    """The tree the family makes has the leaves, shapes and served dtype
+    of the program's own, at the configuration's size (shapes only)."""
+    fam = harness.family_of(_cfg(path))
+    dm = _dims(path)
+    mcfg = fam.model_config(path.stem, dm, 2048)
+    dt = jnp.dtype(mcfg.dtype)
+    ours = jax.eval_shape(lambda: fam.model_weights(2**31 + 5, dm))
+    theirs = jax.eval_shape(lambda k: cast(LM(mcfg).init(k), dt),
+                            jax.random.PRNGKey(0))
+    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
+    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
+        assert (a.shape, a.dtype) == (b.shape, dt)
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_pool_page_bytes_are_the_programs_pages(path):
+    """One entry per pool the program keeps, each a page of that pool
+    over every layer."""
+    fam = harness.family_of(_cfg(path))
+    dm = _dims(path)
+    mcfg = fam.model_config(path.stem, dm, 2048)
+    dt = jnp.dtype(mcfg.dtype)
+    pools = LM(mcfg).pool_shapes(1, 64, dt)
+    assert list(fam.pool_page_bytes(dm, 64)) == [
+        int(np.prod(s.shape)) * dt.itemsize for s in pools]
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_costs_grow_with_the_work(path):
+    fam = harness.family_of(_cfg(path))
+    dm = _dims(path)
+    for f in (fam.decode_flops, fam.decode_bytes):
+        assert 0 < f(dm, [100]) < f(dm, [100, 1]) < f(dm, [100, 300])
+        assert f(dm, [100, 300]) < f(dm, [101, 300])
+    assert 0 < fam.prefill_flops(dm, 1) < fam.prefill_flops(dm, 1024)
 
 
 def test_published_sizes():

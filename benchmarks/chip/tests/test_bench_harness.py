@@ -9,6 +9,7 @@ this file.
 
 import functools
 import gzip
+import importlib
 import json
 import os
 import pathlib
@@ -20,7 +21,6 @@ import time
 import numpy as np
 import pytest
 
-import dense_gqa
 import devtrace
 import harness
 from repro.serving.engine import Request
@@ -34,12 +34,10 @@ SEED = 2**31 + 977
 
 
 def smoke_cell(name: str) -> harness.Cell:
+    """The cell with its configuration cut by its family's ``smoke`` and
+    its traffic and engine cut alike for every cell."""
     cell = harness.load_cell(name)
-    gqa = cell.cfg["num_key_value_heads"] < cell.cfg["num_attention_heads"]
-    cell.cfg.update(hidden_size=64, num_attention_heads=4,
-                    num_key_value_heads=2 if gqa else 4,
-                    num_hidden_layers=2, intermediate_size=128,
-                    vocab_size=512)
+    cell.cfg = cell.family.smoke(cell.cfg)
     m = cell.mix
     m["engine"].update(max_batch=4, max_seq=512, page_tokens=16,
                        frame_pages=4)
@@ -66,8 +64,11 @@ def interpret_movers(monkeypatch):
 
 
 def _run(cell, trace=False, **kw):
+    """A 4 s run of ``cell`` checked by the reference its configuration
+    names."""
+    ref = importlib.import_module(cell.cfg["reference"])
     return harness.run(cell, SEED, 4.0, trace, time.perf_counter(),
-                       dense_gqa.score,
+                       ref.score,
                        device_kind="TPU v5 lite" if trace else None, **kw)
 
 
@@ -94,6 +95,23 @@ def test_a_window_with_no_request_due_reports_no_ttft():
     assert e["ttft_p50_ms"] is None and e["ttft_p90_ms"] is None
     assert e["tokens_per_s"] == pytest.approx(2 / 3.0)
     assert e["itl_p95_ms"] > 0
+
+
+def test_a_family_of_new_files_alone_runs(tmp_path, monkeypatch):
+    """A family module that exists only as a new file under the families
+    directory, here the dense family under another name, runs a cell
+    through ``harness.run``: a configuration names it and nothing else
+    changes."""
+    cell = smoke_cell("qwen2.5-3b.chat")
+    (tmp_path / "standin.py").write_text(
+        (harness.FAMILIES / "dense.py").read_text())
+    monkeypatch.setattr(harness, "FAMILIES", tmp_path)
+    cell.cfg["family"] = "standin"
+    assert cell.family.__file__ == str(tmp_path / "standin.py")
+    out = _run(cell)
+    assert out["correct"], out["check"]
+    assert out["check"]["compared_tokens"]["value"] \
+        >= cell.limits["min_compared_tokens"]
 
 
 def _recorded_trace(log_dir):

@@ -1,5 +1,6 @@
 """The traffic generator: the same work for every seed."""
 
+import hashlib
 import json
 import pathlib
 
@@ -8,8 +9,10 @@ import pytest
 
 import traffic
 
-MIXES = sorted((pathlib.Path(__file__).resolve().parents[1] / "traffic")
-               .glob("*.json"))
+CHIP = pathlib.Path(__file__).resolve().parents[1]
+MIXES = sorted((CHIP / "traffic").glob("*.json"))
+RECORDED = json.loads((CHIP / "tests" / "data"
+                       / "dense_before_families.json").read_text())["plans"]
 
 
 @pytest.mark.parametrize("path", MIXES, ids=lambda p: p.stem)
@@ -53,3 +56,46 @@ def test_a_closed_loop_plan_grows_by_rounds_of_the_same_sizes(path):
               for k in range(3)]
     assert sorted(rounds[0]) == sorted(rounds[1]) == sorted(rounds[2])
     assert rounds[0] != rounds[1]
+
+
+def _digest(reqs) -> str:
+    h = hashlib.sha256()
+    for r in reqs:
+        h.update(repr((r.idx, r.tenant, r.priority, r.max_new,
+                       float(r.at))).encode())
+        h.update(np.asarray(r.prompt, np.int32).tobytes())
+    return h.hexdigest()
+
+
+def _plan(mix, seed):
+    """A 40 s plan over a 151936-token vocabulary; a closed loop's grown
+    to three rounds."""
+    plan = traffic.plan(mix, seed, 151936, 40)
+    if mix["loop"] == "closed":
+        plan.upto(3 * len(plan) - 1)
+    return plan
+
+
+@pytest.mark.parametrize("key", sorted(RECORDED))
+def test_plans_are_the_recorded_ones(key):
+    """Each mix's plan, bit for bit, as the generator gave it before a
+    mix could name its order's seed (``data/dense_before_families.json``)."""
+    mix, seed = key.split("/")
+    plan = _plan(json.loads((CHIP / "traffic" / f"{mix}.json").read_text()),
+                 int(seed))
+    assert _digest(plan) == RECORDED[key]
+
+
+def test_an_order_seed_gives_its_own_fixed_order():
+    """``order_seed`` 601 in a fixed-order mix: another order than the
+    default's, the same for every seed, of the same sizes."""
+    mix = json.loads((CHIP / "traffic" / "tenants-oversub.json").read_text())
+    thrash = dict(mix, order_seed=601)
+    sizes = [[(len(r.prompt), r.max_new) for r in _plan(m, seed)]
+             for m in (mix, thrash) for seed in (11, 2**31 + 11)]
+    assert sizes[0] == sizes[1] and sizes[2] == sizes[3]
+    assert sizes[0] != sizes[2]
+    for i in (0, 1):
+        assert sorted(s[i] for s in sizes[0]) == sorted(s[i] for s in sizes[2])
+    assert _digest(_plan(dict(mix, order_seed=0), 11)) \
+        == _digest(_plan(mix, 11))

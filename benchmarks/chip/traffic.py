@@ -13,10 +13,11 @@ window sees the same mix whatever the seed.  A closed loop has no end of
 its own: its plan is drawn in rounds of ``clients * STRATA`` requests,
 each the same set of lengths in a new order, and grows by a round
 whenever a client needs a request past its end.  A mix with ``"order":
-"fixed"`` takes the orders that seed 0 draws for every seed, so that the
-seed changes only the prompts' tokens (and the weights): for traffic in
-which the order of sizes decides how much work the engine does, such as
-which requests preempt which.
+"fixed"`` takes, for every seed, the orders that the mix's ``order_seed``
+(0 where it is not given) draws, so that the seed changes only the
+prompts' tokens (and the weights): for traffic in which the order of
+sizes decides how much work the engine does, such as which requests
+preempt which.
 """
 
 from __future__ import annotations
@@ -100,8 +101,8 @@ class Plan(list):
 def _rounds(mix: dict, seed: int, vocab: int, n: int
             ) -> Iterator[List[Planned]]:
     rng = np.random.default_rng(seed)
-    order = (np.random.default_rng(0) if mix.get("order") == "fixed"
-             else rng)
+    order = (np.random.default_rng(mix.get("order_seed", 0))
+             if mix.get("order") == "fixed" else rng)
     tenants = mix["tenants"]
     prompt_set = _lognormal_set(mix["prompt_tokens"], n)
     output_set = _lognormal_set(mix["output_tokens"], n)
